@@ -16,6 +16,7 @@ from repro.core.energy import (
     energy_per_mac,
     paper_section6_comparison,
 )
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def run(csv_rows: list) -> dict:
@@ -41,6 +42,7 @@ def run(csv_rows: list) -> dict:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     rows: list = []
     out = run(rows)
     print("\n".join(rows))

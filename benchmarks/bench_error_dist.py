@@ -26,6 +26,7 @@ import jax.numpy as jnp
 
 from repro.core.abfp import QuantConfig, abfp_matmul, pack_abfp_weight
 from repro.kernels.abfp_matmul import abfp_matmul_packed_pallas
+from repro.launch.compile_cache import enable_compile_cache
 
 TILES = (8, 32, 128)
 GAINS = (1.0, 2.0, 4.0, 8.0, 16.0)
@@ -147,6 +148,7 @@ def run(csv_rows: list) -> dict:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     rows: list = []
     out = run(rows)
     print("\n".join(rows))

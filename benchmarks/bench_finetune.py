@@ -21,6 +21,7 @@ import jax.numpy as jnp
 from repro.configs import smoke_config
 from repro.core.abfp import QuantConfig
 from repro.data import DataConfig, batch_at_step
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import init_params
 from repro.optim import AdamW, constant
 from repro.training.finetune import capture_histograms, make_dnf_train_step
@@ -97,6 +98,7 @@ def run(csv_rows: list) -> dict:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     rows: list = []
     out = run(rows)
     print("\n".join(rows))

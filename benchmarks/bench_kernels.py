@@ -29,6 +29,7 @@ from repro.core.abfp import QuantConfig, abfp_matmul, pack_abfp_weight
 from repro.kernels.abfp_decode_fused import fused_qkv_packed_pallas
 from repro.kernels.abfp_matmul import abfp_matmul_packed_pallas, abfp_matmul_pallas
 from repro.kernels.ref import abfp_matmul_ref
+from repro.launch.compile_cache import enable_compile_cache
 
 SCHEMA_VERSION = 2
 
@@ -239,6 +240,7 @@ def run(csv_rows: list) -> dict:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     rows: list = []
     out = run(rows)
     print("\n".join(rows))

@@ -22,6 +22,7 @@ import jax.numpy as jnp
 from repro.configs import smoke_config
 from repro.core.abfp import QuantConfig
 from repro.data import DataConfig, batch_at_step
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import forward, init_params
 from repro.models.layers import Numerics
 from repro.optim import AdamW, constant
@@ -109,6 +110,7 @@ def run(csv_rows: list) -> dict:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     rows: list = []
     out = run(rows)
     print("\n".join(rows))

@@ -52,6 +52,8 @@ import numpy as np
 
 from repro.configs import smoke_config
 from repro.core.abfp import QuantConfig
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_mesh
 from repro.models import init_params, param_count
 from repro.serving import FaultConfig, Request, ServingEngine
 
@@ -683,7 +685,7 @@ def mesh_one(args) -> None:
     parent forces dp*tp placeholder CPU devices via XLA_FLAGS before spawn
     (the flag must be set before first jax use, hence the subprocess)."""
     dp, tp = (int(v) for v in args.mesh_one.split(","))
-    mesh = jax.make_mesh((dp, tp), ("data", "model"))
+    mesh = make_mesh((dp, tp), ("data", "model"))
     mcfg = smoke_config(args.arch)
     params = init_params(jax.random.PRNGKey(args.seed), mcfg)
     chunks = tuple(int(c) for c in args.chunks.split(","))
@@ -705,6 +707,9 @@ def mesh_sweep(args) -> list:
     rows = []
     for dp, tp in MESH_SHAPES:
         env = dict(os.environ)
+        # Placeholder CPU devices, by design: the child must not contend
+        # with this process (or another) for an accelerator.
+        env["JAX_PLATFORMS"] = "cpu"
         env["XLA_FLAGS"] = (
             env.get("XLA_FLAGS", "")
             + f" --xla_force_host_platform_device_count={dp * tp}").strip()
@@ -802,6 +807,7 @@ def main() -> None:
                          "device less than the blocking engine on this "
                          "host (the CI async gate)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.mesh_one:
         mesh_one(args)

@@ -16,6 +16,7 @@ import json
 import os
 
 from repro.configs import SHAPES, get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import HBM_BW, ICI_BW, PEAK_FLOPS_BF16
 from repro.models import param_count
 import jax
@@ -192,6 +193,7 @@ def run(csv_rows: list) -> dict:
 
 
 if __name__ == "__main__":
+    enable_compile_cache()
     csv: list = []
     out = run(csv)
     print("\n".join(csv))

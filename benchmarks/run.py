@@ -15,6 +15,8 @@ import traceback
 
 
 def main() -> None:
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (
         bench_energy,
         bench_error_dist,

@@ -53,7 +53,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.abfp import PackedWeight, QuantConfig, code_dtype
-from repro.kernels._compat import CompilerParams as _CompilerParams
 from repro.kernels.abfp_matmul import (
     DEFAULT_BN,
     _abfp_contrib,
@@ -61,6 +60,7 @@ from repro.kernels.abfp_matmul import (
     _seed_smem,
     auto_bm,
     default_bk,
+    k_blocked,
 )
 
 _MODEL_AXIS = "model"       # mirrors kernels.ops._MODEL_AXIS
@@ -75,8 +75,8 @@ def _fused_qkv_kernel(
     seed_ref,  # SMEM (3, 2) int32: [seed, col-block offset] per segment
     x_ref,     # VMEM (bm, bk) f32
     wc_ref,    # VMEM (bk, bn) int8 codes (concatenated segments)
-    sw_ref,    # VMEM (tk, bn) scales
-    *refs,     # [g_ref (tk, 1) f32 gains]  o_ref (bm, bn)  acc_ref scratch
+    sw_ref,    # VMEM (1, tk, bn) scales
+    *refs,     # [g_ref (1, 1, tk, 1, 1) f32 gains]  o_ref (bm, bn)  acc
     cfg: QuantConfig,
     tk: int,
     n: int,
@@ -96,7 +96,7 @@ def _fused_qkv_kernel(
     """
     if has_gains:
         g_ref, o_ref, acc_ref = refs
-        g = g_ref[...].astype(jnp.float32).reshape(tk)
+        g = g_ref[0, 0].astype(jnp.float32)                 # (tk, 1, 1)
     else:
         o_ref, acc_ref = refs
         g = None
@@ -114,7 +114,7 @@ def _fused_qkv_kernel(
     xt = x_ref[...].astype(jnp.float32).reshape(bm, tk, n)
     cdt = code_dtype(max(cfg.bits_x, cfg.bits_w))
     wq = wc_ref[...].astype(cdt).reshape(tk, n, bn)
-    sw = sw_ref[...].astype(jnp.float32)
+    sw = sw_ref[0].astype(jnp.float32)
 
     # Segment bookkeeping: scalar selects on the (static) boundaries.  The
     # per-segment SMEM rows carry [seed, tensor-parallel col-block offset].
@@ -249,7 +249,7 @@ def fused_qkv_packed_pallas(
     seg_starts = (0, njs[0], njs[0] + njs[1])
     seg_nj = tuple(num_col_blocks) if num_col_blocks is not None else njs
     nj_tot = sum(njs)
-    tk = bk // n
+    nk, tk = kp // bk, bk // n
 
     wcs, sws, gcols = [], [], []
     for pw, nj_s in zip(pws, njs):
@@ -273,7 +273,7 @@ def fused_qkv_packed_pallas(
     seed = jnp.stack([_seed_smem(s, cfg.noise_lsb, o)
                       for s, o in zip(seeds, offs)])   # (3, 2) int32
 
-    grid = (mp // bm, nj_tot, kp // bk)
+    grid = (mp // bm, nj_tot, nk)
     kernel = functools.partial(
         _fused_qkv_kernel, cfg=cfg, tk=tk, n=n,
         seg_starts=seg_starts, seg_nj=seg_nj, has_gains=has_gains)
@@ -281,17 +281,19 @@ def fused_qkv_packed_pallas(
         pl.BlockSpec(memory_space=pltpu.SMEM),                 # seeds
         pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),        # x
         pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),        # codes
-        pl.BlockSpec((tk, bn), lambda i, j, k: (k, j)),        # scales
+        pl.BlockSpec((1, tk, bn), lambda i, j, k: (k, 0, j)),  # scales
     ]
-    inputs = [seed, x2, wc, sw]
+    inputs = [seed, x2, wc, k_blocked(sw, nk)]
     if has_gains:
         # Per-(tile, column-block) gains: column j of the (T, nj_tot) table
         # is the owning segment's per-tile gain vector, so each grid cell
-        # reads its own segment's gains with the same (tk, 1) block the
-        # stand-alone packed kernel uses.
+        # reads its own segment's gains, blocked over K as the stand-alone
+        # packed kernel blocks them.
         gcol = jnp.concatenate(gcols, axis=1)          # (kp/n, nj_tot)
-        in_specs.append(pl.BlockSpec((tk, 1), lambda i, j, k: (k, j)))
-        inputs.append(gcol)
+        gcol = gcol.reshape(nk, tk, nj_tot).transpose(0, 2, 1)
+        in_specs.append(pl.BlockSpec((1, 1, tk, 1, 1),
+                                     lambda i, j, k: (k, j, 0, 0, 0)))
+        inputs.append(gcol.reshape(nk, nj_tot, tk, 1, 1))
 
     out = pl.pallas_call(
         kernel,
@@ -300,7 +302,7 @@ def fused_qkv_packed_pallas(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, nj_tot * bn), cfg.out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -322,38 +324,36 @@ def fused_qkv_packed_pallas(
 
 def _fused_attn_kernel(len_ref, q_ref, kc_ref, ks_ref, vc_ref, vs_ref,
                        o_ref):
-    """Per-batch-element decode attention on int8 KV codes.
+    """Decode attention on int8 KV codes for one (batch element, KV head).
 
-    Mirrors ``models.layers.quantized_decode_attention`` op-for-op for one
-    batch element: scores contract head_dim against the raw int8 codes, the
-    per-position scales factor out of both contractions, masked positions
-    get the same -1e30 the jnp path uses, and the single query row makes
-    the flash-attention online softmax (``flash_attention.py``) degenerate
-    to one ``jax.nn.softmax`` over the key axis.
+    Mirrors ``models.layers.quantized_decode_attention`` op-for-op for the
+    ``rep`` query heads sharing this KV head: scores contract head_dim
+    against the raw int8 codes, the per-position scales factor out of both
+    contractions, masked positions get the same -1e30 the jnp path uses,
+    and the single query row makes the flash-attention online softmax
+    (``flash_attention.py``) degenerate to one ``jax.nn.softmax`` over the
+    key axis.
     """
     b = pl.program_id(0)
-    h, d = q_ref.shape[-2], q_ref.shape[-1]
-    s_max, kh = kc_ref.shape[1], kc_ref.shape[2]
-    rep = h // kh
+    d = q_ref.shape[-1]
+    s_max = kc_ref.shape[0]
+    rep = q_ref.shape[0]
 
-    qf = q_ref[0, 0].astype(jnp.float32) * (d ** -0.5)          # (h, d)
-    qg = qf.reshape(kh, rep, d)
-    kc = kc_ref[0].astype(jnp.float32)                          # (s, kh, d)
-    # scores: einsum "grd,sgd->grs" (batch kh, contract d)
+    qf = q_ref[...].astype(jnp.float32) * (d ** -0.5)           # (rep, d)
+    kc = kc_ref[...].astype(jnp.float32)                        # (s, d)
     s = jax.lax.dot_general(
-        qg, kc, dimension_numbers=(((2,), (2,)), ((0,), (1,))),
-        preferred_element_type=jnp.float32)                     # (kh, rep, s)
-    s = s * (ks_ref[0].astype(jnp.float32).T[:, None, :] / 127.0)
-    pos = jax.lax.broadcasted_iota(jnp.int32, (kh, rep, s_max), 2)
+        qf, kc, dimension_numbers=(((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)                     # (rep, s)
+    s = s * (ks_ref[...].astype(jnp.float32) / 127.0)           # (1, s)
+    pos = jax.lax.broadcasted_iota(jnp.int32, (rep, s_max), 1)
     s = jnp.where(pos < len_ref[b], s, -1e30)
-    p = jax.nn.softmax(s, axis=-1)                              # (kh, rep, s)
-    pv = p * (vs_ref[0].astype(jnp.float32).T[:, None, :] / 127.0)
-    # PV: einsum "grs,sgd->grd" (batch kh, contract s)
+    p = jax.nn.softmax(s, axis=-1)                              # (rep, s)
+    pv = p * (vs_ref[...].astype(jnp.float32) / 127.0)
     out = jax.lax.dot_general(
-        pv, vc_ref[0].astype(jnp.float32),
-        dimension_numbers=(((2,), (0,)), ((0,), (1,))),
-        preferred_element_type=jnp.float32)                     # (kh, rep, d)
-    o_ref[0, 0] = out.reshape(h, d).astype(o_ref.dtype)
+        pv, vc_ref[...].astype(jnp.float32),
+        dimension_numbers=(((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)                     # (rep, d)
+    o_ref[...] = out.astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -366,7 +366,7 @@ def fused_quantized_decode_attention(
     interpret: Optional[bool] = None,
 ) -> jax.Array:
     """Pallas decode attention over the int8 KV cache, one grid cell per
-    batch element.
+    (batch element, KV head).
 
     Same signature and bit-identical output as
     ``models.layers.quantized_decode_attention`` (enforced by
@@ -374,26 +374,38 @@ def fused_quantized_decode_attention(
     traversing XLA's intermediate materializations of the batched einsum
     chain.  ``q``: (B, 1, H, D); codes: (B, S, KH, D) int8; scales:
     (B, S, KH); ``lengths``: (B,) int32 filled-slot counts.
+
+    Each cell holds one head's (S, D) code planes in fast memory.  The TPU
+    tiles the two minor axes of a block: a cache row as stored, (S, KH, D),
+    pads KH up to a sublane tile and D up to 128 lanes, which at S = 2048 no
+    longer fits; and one of KH rows cannot be a block of it.  So the cache
+    is handed over head-major, (B, KH, S, D), a copy per call.
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
     b, _, h, d = q.shape
     s_max, kh = k_codes.shape[1], k_codes.shape[2]
-    return pl.pallas_call(
+    rep = h // kh
+    kc = jnp.swapaxes(k_codes, 1, 2)
+    vc = jnp.swapaxes(v_codes, 1, 2)
+    # (S,) scale rows get a unit sublane axis, for the same reason.
+    ks = k_scale.transpose(0, 2, 1).reshape(b, kh, 1, s_max)
+    vs = v_scale.transpose(0, 2, 1).reshape(b, kh, 1, s_max)
+    head = pl.BlockSpec((None, None, rep, d), lambda i, g: (i, g, 0, 0))
+    codes = pl.BlockSpec((None, None, s_max, d), lambda i, g: (i, g, 0, 0))
+    scale = pl.BlockSpec((None, None, 1, s_max), lambda i, g: (i, g, 0, 0))
+    out = pl.pallas_call(
         _fused_attn_kernel,
-        grid=(b,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),                # lengths
-            pl.BlockSpec((1, 1, h, d), lambda i: (i, 0, 0, 0)),   # q
-            pl.BlockSpec((1, s_max, kh, d), lambda i: (i, 0, 0, 0)),
-            pl.BlockSpec((1, s_max, kh), lambda i: (i, 0, 0)),
-            pl.BlockSpec((1, s_max, kh, d), lambda i: (i, 0, 0, 0)),
-            pl.BlockSpec((1, s_max, kh), lambda i: (i, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, h, d), lambda i: (i, 0, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, 1, h, d), q.dtype),
+        grid=(b, kh),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),         # lengths
+                  head, codes, scale, codes, scale],
+        out_specs=head,
+        out_shape=jax.ShapeDtypeStruct((b, kh, rep, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
-    )(lengths.astype(jnp.int32), q, k_codes, k_scale, v_codes, v_scale)
+    )(lengths.astype(jnp.int32), q.reshape(b, kh, rep, d), kc, ks, vc, vs)
+    return out.reshape(b, 1, h, d)
 
 
 # ---------------------------------------------------------------------------

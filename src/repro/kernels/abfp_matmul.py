@@ -80,7 +80,6 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.abfp import PackedWeight, QuantConfig
-from repro.kernels._compat import CompilerParams as _CompilerParams
 
 DEFAULT_BM = 128
 DEFAULT_BN = 128
@@ -127,7 +126,9 @@ def _hash_uniform(shape, seed, salt):
     x = x ^ (x >> 13)
     x = x * jnp.uint32(0xC2B2AE35)
     x = x ^ (x >> 16)
-    return (x >> 8).astype(jnp.float32) / jnp.float32(1 << 24)
+    # The TPU compiler has no uint32 -> f32 cast; the top 24 bits fit int32
+    # exactly, so going through int32 draws the same value.
+    return (x >> 8).astype(jnp.int32).astype(jnp.float32) / jnp.float32(1 << 24)
 
 
 # ---------------------------------------------------------------------------
@@ -155,11 +156,11 @@ def _abfp_contrib(xt, wq, sw, seed_ref, cfg: QuantConfig, tk: int, n: int,
     unsharded at any shard count (kernels/ops.dense_tp).  Defaults (offset
     0, nj = num_programs(1)) reproduce the historical single-device salts.
 
-    ``g`` (optional (tk,) f32): per-tile ADC gains (``PackedWeight.gains``,
+    ``g`` (optional (tk, 1, 1) f32): per-tile ADC gains (``PackedWeight.gains``,
     the paper's amplification knob).  Each tile's exact partial product is
     amplified by G_t before the b_Y-bit output quantizer
     (``v = p * adc_base_scale * G_t``) and divided back out of that tile's
-    Eq. 6 term (``yq * s_x * s_w / G_t``) — raising effective output
+    Eq. 6 term (``yq * s_x * (s_w / G_t)``) — raising effective output
     precision by log2(G_t) bits with no extra output bits.  ``None`` keeps
     the scalar ``cfg.gain`` path byte-for-byte unchanged; an all-ones ``g``
     is bit-identical to the scalar path at ``gain=1.0`` (amplifying and
@@ -203,7 +204,7 @@ def _abfp_contrib(xt, wq, sw, seed_ref, cfg: QuantConfig, tk: int, n: int,
     if g is None:
         v = p * jnp.float32(cfg.adc_code_scale)
     else:
-        v = p * jnp.float32(cfg.adc_base_scale) * g[:, None, None]
+        v = p * jnp.float32(cfg.adc_base_scale) * g
     if cfg.noise_lsb > 0.0:
         # One independent uniform noise draw per partial output, in LSB
         # units, salted by the grid position.
@@ -226,15 +227,21 @@ def _abfp_contrib(xt, wq, sw, seed_ref, cfg: QuantConfig, tk: int, n: int,
     ly = jnp.float32(2 ** (cfg.bits_y - 1) - 1)
     yq = jnp.clip(jnp.round(v), -ly, ly) * jnp.float32(cfg.bin_y)
 
-    # Eq. 6: rescale partials and sum over the tk tiles in FLOAT32 (per-tile
-    # gains divide out inside the sum; the scalar gain after it).
+    # Eq. 6: rescale partials and sum over the tk tiles in FLOAT32.  Per-tile
+    # gains (powers of two) divide out of the weight scales exactly; the
+    # scalar gain divides the sum.  The sum runs tile by tile in a fixed
+    # order, each tile's rescale feeding its add: a reduction leaves the
+    # order, and whether a multiply and add fuse, to the compiler, which
+    # decides per program, so kernels sharing this core (and the gain and
+    # gain-free paths at unit gains) would stop agreeing bit for bit.
+    sxb = sx.T[:, :, None]                           # (tk, bm, 1)
+    swb = sw[:, None, :] if g is None else sw[:, None, :] / g
+    acc = yq[0] * sxb[0] * swb[0]
+    for t in range(1, tk):
+        acc = acc + yq[t] * sxb[t] * swb[t]
     if g is None:
-        return jnp.sum(
-            yq * sx.T[:, :, None] * sw[:, None, :], axis=0
-        ) / jnp.float32(cfg.gain)                    # (bm, bn)
-    return jnp.sum(
-        yq * sx.T[:, :, None] * sw[:, None, :] / g[:, None, None], axis=0
-    )                                                # (bm, bn)
+        acc = acc / jnp.float32(cfg.gain)
+    return acc                                       # (bm, bn)
 
 
 def _abfp_matmul_kernel(
@@ -288,6 +295,16 @@ def _abfp_matmul_kernel(
 
 def _ceil_to(v: int, m: int) -> int:
     return ((v + m - 1) // m) * m
+
+
+def k_blocked(scales: jax.Array, nk: int) -> jax.Array:
+    """(T, N) per-tile rows -> (nk, T/nk, N): one leading entry per K block.
+
+    A K block holds tk = bk/n tiles, and tk is rarely a multiple of the 8
+    rows the TPU tiles a block by; a (tk, bn) block of the 2-D array is
+    then illegal, while (1, tk, bn) of this view spans its full tile axis.
+    """
+    return scales.reshape(nk, scales.shape[0] // nk, scales.shape[1])
 
 
 def _seed_smem(seed, noise_lsb: float, col_block_offset) -> jax.Array:
@@ -368,7 +385,7 @@ def abfp_matmul_pallas(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), cfg.out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
@@ -386,8 +403,8 @@ def _abfp_matmul_packed_kernel(
     seed_ref,  # SMEM (2,) int32: [seed, col-block offset]
     x_ref,     # VMEM (bm, bk) f32
     wc_ref,    # VMEM (bk, bn) int8 weight codes
-    sw_ref,    # VMEM (tk, bn) scale_dtype weight scales
-    *refs,     # [g_ref (tk, 1) f32 gains]  o_ref (bm, bn)  acc_ref scratch
+    sw_ref,    # VMEM (1, tk, bn) scale_dtype weight scales
+    *refs,     # [g_ref (1, tk, 1, 1) f32 gains]  o_ref (bm, bn)  acc scratch
     cfg: QuantConfig,
     tk: int,
     n: int,
@@ -398,7 +415,7 @@ def _abfp_matmul_packed_kernel(
     gains) stream straight from HBM into the shared ABFP core."""
     if has_gains:
         g_ref, o_ref, acc_ref = refs
-        g = g_ref[...].astype(jnp.float32).reshape(tk)
+        g = g_ref[0].astype(jnp.float32)                 # (tk, 1, 1)
     else:
         o_ref, acc_ref = refs
         g = None
@@ -420,7 +437,7 @@ def _abfp_matmul_packed_kernel(
     from repro.core.abfp import code_dtype
     cdt = code_dtype(max(cfg.bits_x, cfg.bits_w))
     wq = wc_ref[...].astype(cdt).reshape(tk, n, bn)  # (tk, n, bn)
-    sw = sw_ref[...].astype(jnp.float32)             # (tk, bn)
+    sw = sw_ref[0].astype(jnp.float32)               # (tk, bn)
 
     acc_ref[...] += _abfp_contrib(xt, wq, sw, seed_ref, cfg, tk, n, nj=nj,
                                   g=g)
@@ -515,7 +532,7 @@ def abfp_matmul_packed_pallas(
     seed = _seed_smem(seed, cfg.noise_lsb, col_block_offset)
 
     grid = (mp // bm, np_ // bn, kp // bk)
-    tk = bk // n
+    nk, tk = kp // bk, bk // n
 
     has_gains = pw.gains is not None
     kernel = functools.partial(
@@ -525,17 +542,17 @@ def abfp_matmul_packed_pallas(
         pl.BlockSpec(memory_space=pltpu.SMEM),                 # seed
         pl.BlockSpec((bm, bk), lambda i, j, k: (i, k)),        # x
         pl.BlockSpec((bk, bn), lambda i, j, k: (k, j)),        # codes
-        pl.BlockSpec((tk, bn), lambda i, j, k: (k, j)),        # scales
+        pl.BlockSpec((1, tk, bn), lambda i, j, k: (k, 0, j)),  # scales
     ]
-    inputs = [seed, x2, wc, sw]
+    inputs = [seed, x2, wc, k_blocked(sw, nk)]
     if has_gains:
-        # Per-tile gains ride along as a (T, 1) column, blocked over K like
-        # the scales (pad tiles amplify zero scales: exact no-ops).
+        # Per-tile gains ride along blocked over K like the scales (pad
+        # tiles amplify zero scales: exact no-ops).
         gp = jnp.pad(pw.gains.astype(jnp.float32),
-                     (0, kp // n - pw.num_tiles),
-                     constant_values=1.0).reshape(-1, 1)
-        in_specs.append(pl.BlockSpec((tk, 1), lambda i, j, k: (k, 0)))
-        inputs.append(gp)
+                     (0, kp // n - pw.num_tiles), constant_values=1.0)
+        in_specs.append(
+            pl.BlockSpec((1, tk, 1, 1), lambda i, j, k: (k, 0, 0, 0)))
+        inputs.append(gp.reshape(nk, tk, 1, 1))
     out = pl.pallas_call(
         kernel,
         grid=grid,
@@ -543,7 +560,7 @@ def abfp_matmul_packed_pallas(
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), cfg.out_dtype),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

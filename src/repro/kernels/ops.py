@@ -217,15 +217,26 @@ def dense_tp(x: jax.Array, w, cfg: QuantConfig,
     Bit-identical to the single-device call (see module comment).  Falls
     back to the single-device path when the weight is not shardable at this
     mesh (indivisible columns, abfp_ref mode, stacked weights) — the
-    fallback runs replicated under GSPMD, still correct at any mesh shape.
+    fallback runs replicated, still correct at any mesh shape.  A Pallas
+    kernel there runs whole on every device inside a replicated shard_map:
+    the compiler cannot partition a TPU kernel, not even a replicated one.
     """
     from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     if not tp_shardable(w, cfg, mesh):
-        if isinstance(w, PackedWeight):
-            return dense_packed(x, w, cfg, key)
-        return dense(x, w, cfg, key)
+        packed = isinstance(w, PackedWeight)
+
+        def whole(x_, w_, key_):
+            if packed:
+                return dense_packed(x_, w_, cfg, key_)
+            return dense(x_, w_, cfg, key_)
+
+        kernel = packed or cfg.mode not in ("float", "abfp_ref")
+        if mesh is None or mesh.devices.size == 1 or not kernel:
+            return whole(x, w, key)
+        return shard_map(whole, mesh=mesh, in_specs=(P(), P(), P()),
+                         out_specs=P(), check_rep=False)(x, w, key)
 
     tp = tp_size(mesh)
     seed = _key_to_seed(key)

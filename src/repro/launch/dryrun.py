@@ -257,9 +257,6 @@ def _run_cell(arch: str, shape_name: str, mesh, mesh_name: str, quant: str,
     compiled = lowered.compile()
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
-    # jax < 0.5 returns a one-element list of dicts; newer returns the dict.
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else None
     hlo = compiled.as_text()
     # Loop-aware costs: cost_analysis() counts while bodies (= every
     # lax.scan: layers, microbatches, attention chunks) only ONCE; the HLO
@@ -344,7 +341,7 @@ def main() -> int:
     if args.mesh_shape:
         shape = tuple(int(x) for x in args.mesh_shape.split(","))
         axes = ("pod", "data", "model")[-len(shape):]
-        mesh = jax.make_mesh(shape, axes)
+        mesh = mesh_lib.make_mesh(shape, axes)
         mesh_name = "x".join(map(str, shape))
     else:
         mesh = mesh_lib.make_production_mesh(multi_pod=args.multi_pod)

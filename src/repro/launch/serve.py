@@ -42,16 +42,29 @@ import dataclasses
 import json
 import os
 import time
-from typing import List, Optional, Tuple
+from typing import List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
 
 from repro.configs import get_config, list_archs, smoke_config
 from repro.core.abfp import QuantConfig
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import make_mesh
 from repro.models import frontends, init_params, param_count
 from repro.serving import FaultConfig, Request, ServingEngine
 from repro.serving.runners import EncDecRunner, runner_for
+
+
+class Served(NamedTuple):
+    """What one ``main`` call served: the engine (its params, counters and
+    compiled steps), the finished requests, and the wall seconds spent
+    compiling before traffic and serving it."""
+
+    engine: ServingEngine
+    requests: List[Request]
+    compile_s: float
+    serve_s: float
 
 
 def parse_mesh(arg: Optional[str]) -> Optional[Tuple[int, int]]:
@@ -164,7 +177,7 @@ def trace_workload(mcfg, args, rng: np.random.Generator) -> List[Request]:
     return reqs
 
 
-def serve_fleet(built: dict, quant: QuantConfig, mesh, args) -> None:
+def serve_fleet(built: dict, quant: QuantConfig, mesh, args) -> Served:
     """Multi-model fleet serving: one lane per ``--archs`` entry on a
     shared clock, requests routed round-robin across models (enc-dec lanes
     get stub frontend features per request)."""
@@ -203,9 +216,9 @@ def serve_fleet(built: dict, quant: QuantConfig, mesh, args) -> None:
         r.prompt = [t % (vmax - 1) + 1 for t in r.prompt]
     attach_features(reqs, runners, args.seed)
 
-    t0 = time.time()
+    t0 = time.perf_counter()
     done = eng.run(reqs)
-    dt = time.time() - t0
+    dt = time.perf_counter() - t0
     tokens = sum(len(r.generated) for r in done)
     print(f"[serve] fleet: {len(done)} requests, {tokens} tokens in "
           f"{dt:.1f}s ({tokens / max(dt, 1e-9):.1f} tok/s, "
@@ -229,9 +242,12 @@ def serve_fleet(built: dict, quant: QuantConfig, mesh, args) -> None:
             json.dump({"fleet": {n: summaries[n] for n in names},
                        "conservation": cons}, f, indent=2, default=str)
         print(f"[serve] wrote {args.metrics_out}")
+    return Served(eng, done, 0.0, dt)
 
 
-def main() -> None:
+def main(argv: Optional[Sequence[str]] = None) -> Served:
+    """Serve from the command line (``argv``; ``sys.argv`` when None) and
+    return what was served."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="smollm-360m",
                     help="model architecture (see repro.configs.list_archs)")
@@ -359,7 +375,7 @@ def main() -> None:
     ap.add_argument("--inflight", type=int, default=4,
                     help="dispatch-ahead depth for --overlap (bound on "
                          "submitted-but-undelivered passes)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
     if args.overlap:
         args.wall_clock = True
 
@@ -373,7 +389,8 @@ def main() -> None:
                 f"devices but jax was already initialized with "
                 f"{len(jax.devices())}; set XLA_FLAGS="
                 f"--xla_force_host_platform_device_count yourself")
-        mesh = jax.make_mesh(mesh_shape, ("data", "model"))
+        mesh = make_mesh(mesh_shape, ("data", "model"))
+    enable_compile_cache()
 
     archs = resolve_archs(args)
     built = {}
@@ -403,8 +420,7 @@ def main() -> None:
         if args.fault_rate is not None:
             raise SystemExit("[serve] --archs (fleet mode) does not "
                              "compose with fault injection flags yet")
-        serve_fleet(built, quant, mesh, args)
-        return
+        return serve_fleet(built, quant, mesh, args)
 
     mesh_note = (f", mesh=({mesh_shape[0]}x{mesh_shape[1]} data x model)"
                  if mesh is not None else "")
@@ -459,8 +475,12 @@ def main() -> None:
                         clock=time.perf_counter if args.wall_clock else None,
                         overlap=args.overlap,
                         inflight=args.inflight)
-    if args.wall_clock:
-        eng.warmup()        # no compile inside the measured serve window
+    t0 = time.perf_counter()
+    eng.warmup()            # no compile inside the measured serve window
+    compile_s = time.perf_counter() - t0
+    print(f"[serve] compiled the decode step and "
+          f"{len(eng.prefill_chunks) if eng.chunked else 0} prefill "
+          f"bucket(s) in {compile_s:.1f}s")
     rng = np.random.default_rng(args.seed)
 
     open_loop = args.arrival_rate is not None or args.trace is not None
@@ -482,9 +502,9 @@ def main() -> None:
                 - min(r.arrival_time for r in reqs)) if reqs else 0.0
         print(f"[serve] open-loop: {len(reqs)} requests arriving over "
               f"{span:.1f} {unit}, {args.tenants} tenants")
-        t0 = time.time()
+        t0 = time.perf_counter()
         done = eng.drain()
-        dt = time.time() - t0
+        dt = time.perf_counter() - t0
     else:
         reqs = [Request(uid=i,
                         prompt=rng.integers(1, mcfg.vocab_size,
@@ -492,9 +512,9 @@ def main() -> None:
                         max_new_tokens=args.max_new,
                         temperature=args.temperature)
                 for i in range(args.requests)]
-        t0 = time.time()
+        t0 = time.perf_counter()
         done = eng.run(reqs)
-        dt = time.time() - t0
+        dt = time.perf_counter() - t0
 
     tokens = sum(len(r.generated) for r in done)
     print(f"[serve] {len(done)} requests, {tokens} tokens in {dt:.1f}s "
@@ -558,6 +578,7 @@ def main() -> None:
         print(f"[serve] wrote {args.metrics_out}")
     for r in done[:3]:
         print(f"  req {r.uid}: prompt[{len(r.prompt)}] -> {r.generated}")
+    return Served(eng, done, compile_s, dt)
 
 
 if __name__ == "__main__":

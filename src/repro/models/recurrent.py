@@ -165,16 +165,14 @@ def init_mlstm_block(key, mcfg, layer_shape=()) -> dict:
     }
 
 
-def _mlstm_chunk_scan(q, k, v, log_i, log_f, state, chunk, valid=None):
+def _mlstm_chunk_scan(q, k, v, log_i, log_f, state, chunk):
     """Chunkwise stabilized mLSTM.  q,k,v: (B, NH, S, D); gates (B, NH, S).
     state: (C (B,NH,D,D), n (B,NH,D), m (B,NH)).  Returns (h, new_state).
 
-    ``valid`` (B, S) bool requires chunk == 1 (each scan step is then one
-    token): steps with valid False leave the carried state unchanged —
-    the chunked-prefill padding semantics.
+    A position with log_i = -1e30 and log_f = 0 (no input, no decay)
+    leaves the carried state exactly unchanged: the padding semantics.
     """
     b, nh, s, dh = q.shape
-    assert valid is None or chunk == 1, "valid mask needs chunk == 1"
     pad = (-s) % chunk
     if pad:
         padf = lambda a, fill=0.0: jnp.pad(  # noqa: E731
@@ -186,10 +184,6 @@ def _mlstm_chunk_scan(q, k, v, log_i, log_f, state, chunk, valid=None):
         log_f = jnp.pad(log_f, ((0, 0), (0, 0), (0, pad)))
     sp = s + pad
     nc = sp // chunk
-    if valid is None:
-        cvalid = jnp.ones((nc, b), bool)
-    else:
-        cvalid = jnp.moveaxis(valid.reshape(b, nc, chunk)[..., 0], 1, 0)
     # (NC, B, NH, c, D) chunked views.
     cq = jnp.moveaxis(q.reshape(b, nh, nc, chunk, dh), 2, 0)
     ck = jnp.moveaxis(k.reshape(b, nh, nc, chunk, dh), 2, 0)
@@ -199,7 +193,7 @@ def _mlstm_chunk_scan(q, k, v, log_i, log_f, state, chunk, valid=None):
 
     def step(carry, xs):
         cmat, n, m = carry                         # (B,NH,D,D),(B,NH,D),(B,NH)
-        qc, kc, vc, li, lf, ok = xs
+        qc, kc, vc, li, lf = xs
         csum = jnp.cumsum(lf, axis=-1)             # (B, NH, c)
         total = csum[..., -1]
         # Decay from chunk start to position t (inclusive of f_t).
@@ -231,11 +225,9 @@ def _mlstm_chunk_scan(q, k, v, log_i, log_f, state, chunk, valid=None):
             "bhs,bhsd,bhse->bhde", k_w, kc * (dh ** -0.5), vc)
         n_new = n * decay_state[..., None] + jnp.einsum(
             "bhs,bhsd->bhd", k_w, kc * (dh ** -0.5))
-        sel = lambda new, old: jnp.where(  # noqa: E731
-            ok.reshape((b,) + (1,) * (new.ndim - 1)), new, old)
-        return (sel(cmat_new, cmat), sel(n_new, n), sel(m_end, m)), h
+        return (cmat_new, n_new, m_end), h
 
-    new_state, hs = jax.lax.scan(step, state, (cq, ck, cv, cli, clf, cvalid))
+    new_state, hs = jax.lax.scan(step, state, (cq, ck, cv, cli, clf))
     h = jnp.moveaxis(hs, 0, 2).reshape(b, nh, sp, dh)[:, :, :s]
     return h, new_state
 
@@ -272,12 +264,18 @@ def mlstm_block(params, x: Array, mcfg, nx: Numerics,
         }
     qf, kf, vf = (t.astype(jnp.float32) for t in (q, k, v))
     if n_tokens is not None:
-        chunk_eff, valid = 1, jnp.arange(s)[None, :] < n_tokens[:, None]
+        # Padding positions get the scan's own padding gates (no input,
+        # no decay), which keep the state exactly: the step then runs the
+        # same arithmetic as a decode tick, with no select around it.
+        chunk_eff = 1
+        valid = (jnp.arange(s)[None, :] < n_tokens[:, None])[:, None, :]
+        log_i = jnp.where(valid, log_i, -1e30)
+        log_f = jnp.where(valid, log_f, 0.0)
     else:
-        chunk_eff, valid = min(chunk, max(s, 1)), None
+        chunk_eff = min(chunk, max(s, 1))
     h, (c_new, n_new, m_new) = _mlstm_chunk_scan(
         qf, kf, vf, log_i, log_f,
-        (state["C"], state["n"], state["m"]), chunk_eff, valid)
+        (state["C"], state["n"], state["m"]), chunk_eff)
     h = h.transpose(0, 2, 1, 3).reshape(b, s, inner)
     h = h + (params["skip_scale"][None, None].astype(jnp.float32)
              * up.astype(jnp.float32))
@@ -342,6 +340,12 @@ def slstm_block(params, x: Array, mcfg, nx: Numerics,
         rec = jnp.einsum("bhd,hde->bhe", h, r_h)             # (B, NH, 4dh)
         g = gx_t.reshape(b, nh, 4 * dh) + rec
         gi, gf, gz, go = jnp.split(g, 4, axis=-1)
+        if n_tokens is not None:
+            # Padding steps: no input (i = 0), no decay (f = 1), so c, n
+            # and m stay exactly as they are with the same arithmetic as
+            # a decode tick; only h needs the select.
+            gi = jnp.where(ok[:, None, None], gi, -jnp.inf)
+            gf = jnp.where(ok[:, None, None], gf, jnp.inf)
         log_f = jax.nn.log_sigmoid(gf)
         m_new = jnp.maximum(log_f + m, gi)                   # stabilizer
         i = jnp.exp(gi - m_new)
@@ -351,9 +355,9 @@ def slstm_block(params, x: Array, mcfg, nx: Numerics,
         c_new = f * c + i * z
         n_new = f * n + i
         h_new = o * c_new / jnp.maximum(n_new, 1.0)
-        sel = lambda new, old: jnp.where(ok[:, None, None], new, old)  # noqa: E731
-        return (sel(h_new, h), sel(c_new, c), sel(n_new, n),
-                sel(m_new, m)), h_new
+        if n_tokens is not None:
+            h_new = jnp.where(ok[:, None, None], h_new, h)
+        return (h_new, c_new, n_new, m_new), h_new
 
     gx_t = jnp.moveaxis(gx, 1, 0)                            # (S, B, 4d)
     valid = (jnp.arange(s)[:, None] < n_tokens[None, :]

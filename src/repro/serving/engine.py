@@ -410,13 +410,7 @@ class ServingEngine:
         if fn is None:
             base = (self._jit_step if shape_key[0] == "decode"
                     else self._jit_prefill)
-            try:
-                fn = base.lower(*args).compile()
-            except Exception:
-                # AOT lowering is best-effort (exotic runner states);
-                # falling back to plain jit dispatch keeps serving correct,
-                # at worst paying compile inside the first timed pass.
-                fn = base
+            fn = base.lower(*args).compile()
             self._cached_pref[shape_key] = fn
         warm = shape_key not in self._warmed_shapes
         self._warmed_shapes.add(shape_key)
@@ -436,20 +430,32 @@ class ServingEngine:
         # pass per shape still carries first-dispatch overhead.
         self._warmed_shapes.clear()
 
+    def compiled_hlo(self, shape_key: Tuple = ("decode",)) -> str:
+        """Optimized HLO of the warmed executable for ``shape_key``: the
+        program the device runs, where Pallas kernels compiled for a TPU
+        appear as ``tpu_custom_call`` custom calls."""
+        return self._cached_pref[shape_key].as_text()
+
     def _call(self, shape_key: Tuple, args: Tuple):
         """Dispatch one pass through the warmed-executable cache.  If the
         AOT executable rejects the concrete arguments (e.g. a sharding
         lowered from a host prototype disagreeing with a live device
         array), fall back to plain jit dispatch for that shape — correct
-        either way, the cache is an optimization."""
+        either way, the cache is an optimization.
+
+        Only that rejection is caught: the executable checks its
+        arguments before it runs (``TypeError`` for types or tree
+        structure, ``ValueError`` for shardings), so nothing was executed
+        or donated yet.  A failure while running propagates: with donated
+        state a re-run would read invalidated buffers."""
         fn, warm = self._executable(shape_key, args)
+        base = (self._jit_step if shape_key[0] == "decode"
+                else self._jit_prefill)
+        if fn is base:
+            return fn(*args), warm
         try:
             return fn(*args), warm
-        except Exception:
-            base = (self._jit_step if shape_key[0] == "decode"
-                    else self._jit_prefill)
-            if fn is base:
-                raise
+        except (TypeError, ValueError):
             self._cached_pref[shape_key] = base
             return base(*args), warm
 
@@ -1255,7 +1261,10 @@ class ServingEngine:
             else:
                 tokens[i, 0] = self._next_input[i]
         if self.paged:
-            self.state["page_table"] = jnp.asarray(self._table)
+            # A private copy: JAX may read a host array after the call
+            # returns (on the CPU backend in place), and the host goes on
+            # editing the table for the passes it dispatches next.
+            self.state["page_table"] = jnp.asarray(self._table.copy())
         temps, uids, idxs = self._samp_arrays()
         self.key, sub = jax.random.split(self.key)
         rv = (self._dev_next if self._dev_next is not None
@@ -1323,11 +1332,15 @@ class ServingEngine:
 
     def _decode_tick(self):
         if self.paged:
-            self.state["page_table"] = jnp.asarray(self._table)
+            # A private copy: JAX may read a host array after the call
+            # returns (on the CPU backend in place), and the host goes on
+            # editing the table for the passes it dispatches next.
+            self.state["page_table"] = jnp.asarray(self._table.copy())
         fed = [i for i, s in enumerate(self.slots) if s is not None]
+        # Host inputs go in as private copies, as the page table does.
         token = (self._dev_next
                  if self.overlap and self._dev_next is not None
-                 else self._next_input)
+                 else self._next_input.copy())
         ov_vals, ov_mask = self._ov_vals.copy(), self._ov_mask.copy()
         temps, uids, idxs = self._samp_arrays()
         self.key, sub = jax.random.split(self.key)

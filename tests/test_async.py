@@ -42,6 +42,7 @@ import pytest
 from repro.configs import smoke_config
 from repro.core.abfp import QuantConfig
 from repro.distributed.fault import StragglerMonitor
+from repro.launch.mesh import make_mesh
 from repro.models import init_params
 from repro.serving import (
     DeviceStream,
@@ -131,7 +132,7 @@ def test_overlap_parity_mesh_2x4(tinyllama, quant):
     """The overlapped pipeline under the full (dp, tp) = (2, 4) mesh emits
     the same tokens as the simulated blocking engine on the same mesh."""
     params, mcfg = tinyllama
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     ref, got, _ = _serve_pair(params, mcfg, quant, mesh=mesh)
     assert got == ref
 

@@ -4,6 +4,7 @@ multi-device MoE equivalence check (8 placeholder CPU devices, subprocess)."""
 import subprocess
 import sys
 import textwrap
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
@@ -21,6 +22,13 @@ from repro.distributed.sharding import (
 )
 from repro.launch.hlo_analysis import collective_stats, roofline_terms
 from repro.models import init_params
+
+
+ROOT = Path(__file__).resolve().parents[1]
+# JAX_PLATFORMS=cpu: the children run on placeholder CPU devices and must
+# not load the TPU library, which one process at a time may hold.
+CHILD_ENV = {"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
+             "JAX_PLATFORMS": "cpu"}
 
 
 class _FakeMesh:
@@ -130,6 +138,7 @@ import dataclasses
 import jax, jax.numpy as jnp, numpy as np
 from repro.configs import smoke_config
 from repro.core.abfp import QuantConfig
+from repro.launch.mesh import make_mesh
 from repro.models.layers import Numerics
 from repro.models import moe as moe_lib
 
@@ -144,7 +153,7 @@ nx = Numerics(QuantConfig(mode="float"))
 
 y_local, aux_local = moe_lib.moe_block(params, x, mcfg, nx)
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 with mesh:
     y_sh, aux_sh = jax.jit(
         lambda p, x: moe_lib.moe_block_sharded(p, x, mcfg, nx, mesh)
@@ -176,8 +185,7 @@ def test_moe_sharded_matches_local():
     """Expert-parallel shard_map MoE == single-shard MoE (8 fake devices)."""
     r = subprocess.run([sys.executable, "-c", _MOE_SCRIPT],
                        capture_output=True, text=True, timeout=560,
-                       env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin"},
-                       cwd="/root/repo")
+                       env=CHILD_ENV, cwd=ROOT)
     assert "MOE_SHARDED_OK" in r.stdout, r.stdout + r.stderr
 
 
@@ -188,6 +196,7 @@ import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import smoke_config
 from repro.distributed.sharding import param_spec_tree, batch_spec
+from repro.launch.mesh import make_mesh
 from repro.models import forward, init_params
 
 mcfg = smoke_config("tinyllama-1.1b")
@@ -196,7 +205,7 @@ toks = jax.random.randint(jax.random.PRNGKey(1), (8, 16), 0, mcfg.vocab_size)
 
 logits_1d, _ = jax.jit(lambda p, t: forward(p, t, mcfg))(params, toks)
 
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 ps = jax.tree.map(lambda s: NamedSharding(mesh, s),
                   param_spec_tree(params, mesh),
                   is_leaf=lambda x: isinstance(x, P))
@@ -216,6 +225,5 @@ def test_sharded_forward_matches_single_device():
     """GSPMD-sharded forward == single-device forward (8 fake devices)."""
     r = subprocess.run([sys.executable, "-c", _SHARDED_FWD_SCRIPT],
                        capture_output=True, text=True, timeout=560,
-                       env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin"},
-                       cwd="/root/repo")
+                       env=CHILD_ENV, cwd=ROOT)
     assert "SHARDED_FWD_OK" in r.stdout, r.stdout + r.stderr

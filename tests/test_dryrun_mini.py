@@ -7,10 +7,14 @@ import os
 import subprocess
 import sys
 import tempfile
+from pathlib import Path
 
 import pytest
 
-ENV = {"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
+ROOT = Path(__file__).resolve().parents[1]
+# JAX_PLATFORMS=cpu: the child compiles for placeholder CPU devices and must
+# not load the TPU library, which one process at a time may hold.
+ENV = {"PYTHONPATH": "src", "PATH": "/usr/bin:/bin", "JAX_PLATFORMS": "cpu",
        "XLA_FLAGS": "--xla_force_host_platform_device_count=8"}
 
 
@@ -22,7 +26,7 @@ def _run(args, timeout=560):
         return subprocess.run(
             [sys.executable, "-m", "repro.launch.dryrun", *args],
             capture_output=True, text=True, timeout=timeout,
-            env={**ENV, "REPRO_DRYRUN_ART_DIR": art}, cwd="/root/repo")
+            env={**ENV, "REPRO_DRYRUN_ART_DIR": art}, cwd=ROOT)
 
 
 @pytest.mark.slow

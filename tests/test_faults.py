@@ -31,6 +31,7 @@ import pytest
 
 from repro.configs import smoke_config
 from repro.core.abfp import QuantConfig, packed_tile_fingerprint
+from repro.launch.mesh import make_mesh
 from repro.models import init_params
 from repro.models.packing import pack_model_params
 from repro.serving import (
@@ -351,7 +352,7 @@ def test_mesh_parity_with_fault_machinery(tinyllama, shape):
     base = ServingEngine(params, mcfg, capacity=4, max_len=64, quant=PACKED,
                          seed=0, prefill_chunks=(4, 8))
     out0 = _tokens(base.run(_workload(mcfg)))
-    mesh = jax.make_mesh(shape, ("data", "model"))
+    mesh = make_mesh(shape, ("data", "model"))
     eng = ServingEngine(params, mcfg, capacity=4, max_len=64, quant=PACKED,
                         seed=0, prefill_chunks=(4, 8), mesh=mesh,
                         faults=FaultConfig(rate=0.0))
@@ -365,7 +366,7 @@ def test_mesh_shard_drop_reshards_and_conserves(tinyllama):
     mcfg, params = tinyllama
     plan = FaultPlan([FaultEvent(6, "shard_drop", "", shard=1)],
                      FaultConfig(rate=0.01))
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     eng = ServingEngine(params, mcfg, capacity=4, max_len=64, quant=PACKED,
                         seed=0, prefill_chunks=(4, 8), mesh=mesh,
                         faults=plan, recovery=True, detect_every=2)

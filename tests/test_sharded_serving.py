@@ -22,6 +22,7 @@ import pytest
 
 from repro.configs import smoke_config
 from repro.core.abfp import QuantConfig
+from repro.launch.mesh import make_mesh
 from repro.models import init_params
 from repro.serving import Request, ServingEngine
 
@@ -90,7 +91,7 @@ def tinyllama_base_packed(tinyllama):
 @pytest.mark.parametrize("shape", MESH_SHAPES)
 def test_float_parity(tinyllama, tinyllama_base_float, shape):
     """Greedy float decode tokens identical to single-device at any mesh."""
-    mesh = jax.make_mesh(shape, ("data", "model"))
+    mesh = make_mesh(shape, ("data", "model"))
     got = _serve(*tinyllama, FLOAT, mesh)
     assert got == tinyllama_base_float, shape
 
@@ -101,7 +102,7 @@ def test_packed_parity_bit_identical(tinyllama, tinyllama_base_packed,
     """abfp_packed greedy decode with ADC noise (fixed seed): bit-identical
     tokens to the single-device engine at any mesh shape — the acceptance
     gate for --mesh 2,4 --quant abfp-packed."""
-    mesh = jax.make_mesh(shape, ("data", "model"))
+    mesh = make_mesh(shape, ("data", "model"))
     got = _serve(*tinyllama, PACKED, mesh)
     assert got == tinyllama_base_packed, shape
 
@@ -118,7 +119,7 @@ def test_fused_parity_bit_identical(tinyllama_kvq,
     per-tile ADC gains) at gain 1.0 emits bit-identical greedy tokens to
     the single-device abfp_packed engine at EVERY mesh shape — dp-only,
     tp-only, and the full (2, 4) mesh, seeded ADC noise included."""
-    mesh = jax.make_mesh(shape, ("data", "model"))
+    mesh = make_mesh(shape, ("data", "model"))
     got = _serve(*tinyllama_kvq, FUSED1, mesh)
     assert got == tinyllama_base_packed1_kvq, shape
 
@@ -129,7 +130,7 @@ def test_fused_gain_mesh_self_parity(tinyllama_kvq, shape):
     mesh engine matches the single-device FUSED engine bit-for-bit: the
     gains table shards/replicates without perturbing a single logit."""
     base = _serve(*tinyllama_kvq, FUSED4, None)
-    mesh = jax.make_mesh(shape, ("data", "model"))
+    mesh = make_mesh(shape, ("data", "model"))
     got = _serve(*tinyllama_kvq, FUSED4, mesh)
     assert got == base, shape
 
@@ -140,7 +141,7 @@ def test_paged_parity_bit_identical(tinyllama, tinyllama_base_float, shape):
     bit-identical to the UNPAGED single-device float baseline at every
     PR-4 mesh shape — the page-table gather must not change a single
     logit under either sharding axis."""
-    mesh = jax.make_mesh(shape, ("data", "model"))
+    mesh = make_mesh(shape, ("data", "model"))
     got = _serve(*tinyllama, FLOAT, mesh, paged=True, page_size=16)
     assert got == tinyllama_base_float, shape
 
@@ -149,7 +150,7 @@ def test_paged_packed_parity_on_mesh(tinyllama, tinyllama_base_packed):
     """abfp_packed + paged KV at the largest mesh shape: tokens identical
     to the single-device UNPAGED packed engine (seeded ADC noise and the
     quantized KV pool both survive the indirection)."""
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     got = _serve(*tinyllama, PACKED, mesh, paged=True, page_size=32)
     assert got == tinyllama_base_packed
 
@@ -167,7 +168,7 @@ def test_ring_cache_wraparound_parity(shape, quant):
     params = init_params(jax.random.PRNGKey(1), mcfg)
     base = _serve(mcfg, params, quant, None, max_new=6, max_len=48)
     assert any(len(p) + 6 > 8 for p in PROMPTS)     # wraps for long prompts
-    mesh = jax.make_mesh(shape, ("data", "model"))
+    mesh = make_mesh(shape, ("data", "model"))
     got = _serve(mcfg, params, quant, mesh, max_new=6, max_len=48)
     assert got == base, shape
 
@@ -189,7 +190,7 @@ def test_open_loop_api_unchanged_under_mesh(tinyllama):
         return {r.uid: tuple(r.generated) for r in done}, eng.ticks
 
     base_tokens, base_ticks = run(None)
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     got_tokens, got_ticks = run(mesh)
     assert got_tokens == base_tokens
     assert got_ticks == base_ticks
@@ -206,7 +207,7 @@ def test_dense_tp_col_parallel_bit_identical():
     from repro.core.abfp import pack_abfp_weight
     from repro.kernels.ops import dense, dense_packed, dense_tp
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     kx, kw, kk = jax.random.split(jax.random.PRNGKey(0), 3)
     x = jax.random.normal(kx, (8, 256), jnp.float32)
     w = jax.random.normal(kw, (256, 512), jnp.float32) * 0.1
@@ -238,7 +239,7 @@ def test_dense_tp_fallback_on_indivisible_columns():
     from repro.core.abfp import pack_abfp_weight
     from repro.kernels.ops import dense_packed, dense_tp, tp_shardable
 
-    mesh = jax.make_mesh((1, 8), ("data", "model"))
+    mesh = make_mesh((1, 8), ("data", "model"))
     cfg = QuantConfig(mode="abfp_packed", tile_width=32, gain=4.0,
                       noise_lsb=0.5, out_dtype=jnp.float32)
     kx, kw, kk = jax.random.split(jax.random.PRNGKey(1), 3)
@@ -259,7 +260,7 @@ def test_dense_tp_row_psum_matches_to_tolerance():
 
     from repro.kernels.ops import dense_tp_row
 
-    mesh = jax.make_mesh((1, 8), ("data", "model"))
+    mesh = make_mesh((1, 8), ("data", "model"))
     kx, kw = jax.random.split(jax.random.PRNGKey(2))
     x = jax.random.normal(kx, (8, 256), jnp.float32)
     w = jax.random.normal(kw, (256, 64), jnp.float32) * 0.1
@@ -281,7 +282,7 @@ def test_packed_params_shard_codes_and_scales_together(tinyllama):
     from repro.models.packing import pack_model_params
 
     mcfg, params = tinyllama
-    mesh = jax.make_mesh((1, 2), ("data", "model"))
+    mesh = make_mesh((1, 2), ("data", "model"))
     packed = pack_model_params(params, PACKED, mcfg, mesh=mesh)
     n_sharded = 0
     for leaf in jax.tree_util.tree_leaves(

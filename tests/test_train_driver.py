@@ -3,17 +3,21 @@ fault-tolerance restart path), QAT mode, and the serve driver."""
 
 import subprocess
 import sys
+from pathlib import Path
 
 import pytest
 
-ENV = {"PYTHONPATH": "src", "PATH": "/usr/bin:/bin"}
+ROOT = Path(__file__).resolve().parents[1]
+# JAX_PLATFORMS=cpu: the entry points run on the CPU here and must not load the
+# TPU library, which one process at a time may hold.
+ENV = {"PYTHONPATH": "src", "PATH": "/usr/bin:/bin", "JAX_PLATFORMS": "cpu"}
 
 
 def _run(mod, *args, timeout=560):
     return subprocess.run(
         [sys.executable, "-m", mod, *args],
         capture_output=True, text=True, timeout=timeout, env=ENV,
-        cwd="/root/repo")
+        cwd=ROOT)
 
 
 @pytest.mark.slow
