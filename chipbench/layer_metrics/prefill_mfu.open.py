@@ -1,0 +1,6 @@
+"""Model step: model FLOPs of the real tokens of the traced prefill
+passes over their device time, as a percentage of the chip's int8 peak."""
+
+
+def read(run):
+    return run.pass_mfu(("prefill",))
