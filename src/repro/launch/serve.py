@@ -538,6 +538,10 @@ def main(argv: Optional[Sequence[str]] = None) -> Served:
           f"slot utilization "
           f"{'-' if util is None else f'{util:.0%}'}, max queue depth "
           f"{s['queue_depth']['max']}")
+    pre = s["prefill"]
+    if pre["rows"]:
+        print(f"[serve] prefill: {pre['tokens']} tokens in {pre['rows']} "
+              f"rows ({pre['pad_share']:.1%} padding)")
     if args.wall_clock:
         tu = s["tick_utilization"]
         tv = tu["value"]

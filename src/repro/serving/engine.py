@@ -110,12 +110,30 @@ projected footprint; pool pressure above ``page_watermarks[0]`` flips
 hysteretic DEGRADED mode (admissions get ``degraded_max_new``, prefill
 drops to the smallest bucket) until pressure falls below
 ``page_watermarks[1]``.
+
+Tracing
+-------
+The engine marks its host work as spans in the JAX profiler's trace
+(``serving.metrics.span``), so a trace taken with ``jax.profiler.trace``
+puts each host step on the clock of the device's executables:
+``serving.admit`` (args ``admitted``, ``queued``), ``serving.step`` around
+all of ``step()``, inside it ``serving.prefill_pass`` (``pass_id``,
+``bucket``, ``rows`` = capacity x bucket, ``tokens`` fed, ``live``) or
+``serving.decode_tick`` (``pass_id``, ``live``), and inside those
+``serving.launch`` (``pass_id``) around the executable's dispatch.  The
+stream adds ``serving.stream_wait``, ``serving.fetch`` (``pass_id``) and,
+on delivery, ``serving.deliver`` (``pass_id``); garbage collections show as
+``python.gc``.  ``pass_id`` is ``ticks`` at dispatch: it ties one pass's
+spans together across threads.  With no profiler running a span records
+nothing, and costs a few microseconds of host time.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import gc
 import time
+import weakref
 from collections import deque
 from typing import Any, Callable, List, Optional, Sequence, Tuple, Union
 
@@ -128,7 +146,7 @@ from repro.core.abfp import QuantConfig
 from repro.distributed.fault import StragglerMonitor, plan_recovery_mesh
 from repro.serving import faults as faultlib
 from repro.serving.faults import FaultConfig, FaultPlan
-from repro.serving.metrics import ServingMetrics
+from repro.serving.metrics import GcSpans, ServingMetrics, span
 from repro.serving.pages import (
     PagePool,
     page_table_array,
@@ -319,6 +337,13 @@ class ServingEngine:
         self._ov_vals = np.zeros((capacity,), np.int32)
         self._ov_mask = np.zeros((capacity,), bool)
 
+        # Collections show in a trace as ``python.gc`` spans.  The hook
+        # holds no reference to the engine; it goes with close(), or with
+        # the engine when it is never closed.
+        hook = GcSpans()
+        gc.callbacks.append(hook)
+        self._unhook_gc = weakref.finalize(self, gc.callbacks.remove, hook)
+
         self.ticks = 0
         self.scheduler = get_scheduler(policy)
         self.metrics = ServingMetrics(capacity)
@@ -451,13 +476,14 @@ class ServingEngine:
         fn, warm = self._executable(shape_key, args)
         base = (self._jit_step if shape_key[0] == "decode"
                 else self._jit_prefill)
-        if fn is base:
-            return fn(*args), warm
-        try:
-            return fn(*args), warm
-        except (TypeError, ValueError):
-            self._cached_pref[shape_key] = base
-            return base(*args), warm
+        with span("serving.launch", pass_id=self.ticks):
+            if fn is base:
+                return fn(*args), warm
+            try:
+                return fn(*args), warm
+            except (TypeError, ValueError):
+                self._cached_pref[shape_key] = base
+                return base(*args), warm
 
     # -- dispatch inputs --------------------------------------------------
     def _samp_arrays(self):
@@ -532,24 +558,25 @@ class ServingEngine:
         device->host transfer on the overlapped hot path — append values,
         fire streaming callbacks, finalize metrics, and feed the
         straggler/utilization gauges."""
-        vals = self._stream.fetch(ticket.sampled)
-        done = self._perf()
-        self.metrics.on_device_span(ticket.t0, done)
-        if not ticket.warmup:
-            self.straggler.observe(done - ticket.t0)
-        for rec in ticket.recs:
-            req = rec.req
-            nxt = int(vals[rec.slot])
-            req.generated.append(nxt)
-            self.metrics.on_token(req.uid, ticket.now)
-            if rec.corrupted:
-                self.metrics.on_corrupted(req.uid)
-            if req.on_token is not None:
-                req.on_token(req, nxt)
-            if rec.finishing:
-                req.done = True
-                self.metrics.on_finish(req.uid, ticket.now)
-                self._delivered.append(req)
+        vals = self._stream.fetch(ticket.sampled, pass_id=ticket.pass_id)
+        with span("serving.deliver", pass_id=ticket.pass_id):
+            done = self._perf()
+            self.metrics.on_device_span(ticket.t0, done)
+            if not ticket.warmup:
+                self.straggler.observe(done - ticket.t0)
+            for rec in ticket.recs:
+                req = rec.req
+                nxt = int(vals[rec.slot])
+                req.generated.append(nxt)
+                self.metrics.on_token(req.uid, ticket.now)
+                if rec.corrupted:
+                    self.metrics.on_corrupted(req.uid)
+                if req.on_token is not None:
+                    req.on_token(req, nxt)
+                if rec.finishing:
+                    req.done = True
+                    self.metrics.on_finish(req.uid, ticket.now)
+                    self._delivered.append(req)
 
     def _drain_delivered(self) -> List[Request]:
         out: List[Request] = []
@@ -565,11 +592,13 @@ class ServingEngine:
         self._stream.sync()
 
     def close(self):
-        """Shut down the background delivery worker.  Safe on any engine;
-        an engine sharing a fleet-owned stream leaves it to the fleet."""
+        """Shut down the background delivery worker and remove the
+        ``python.gc`` hook.  Safe on any engine; an engine sharing a
+        fleet-owned stream leaves the stream to the fleet."""
         if self._owns_stream:
             self._stream.sync()
             self._stream.close()
+        self._unhook_gc()
 
     # -- clock ----------------------------------------------------------------
     def _tick_clock(self):
@@ -759,20 +788,23 @@ class ServingEngine:
         preemption) whose deadline has since passed must be timed out here,
         never re-admitted — its expiry is surfaced through the same poll
         that would have admitted it."""
-        if self._has_deadlines:
-            self._returned.extend(self._expire_queue())
-        admitted: List[Request] = []
-        free = self.slots.count(None)
-        while free > 0:
-            req = self.scheduler.pop(
-                self.now, self._admissible if self.paged else None)
-            if req is None:
-                break
-            self.try_admit(req)     # a slot is free; fits() held at submit
-            admitted.append(req)
-            free -= 1
-        if self.paged and self.preemption:
-            self._priority_claim(admitted)
+        with span("serving.admit") as sp:
+            if self._has_deadlines:
+                self._returned.extend(self._expire_queue())
+            admitted: List[Request] = []
+            free = self.slots.count(None)
+            while free > 0:
+                req = self.scheduler.pop(
+                    self.now, self._admissible if self.paged else None)
+                if req is None:
+                    break
+                self.try_admit(req)     # a slot is free; fits() held
+                admitted.append(req)
+                free -= 1
+            if self.paged and self.preemption:
+                self._priority_claim(admitted)
+            sp.set_metadata(admitted=len(admitted),
+                            queued=len(self.scheduler))
         return admitted
 
     def _priority_claim(self, admitted: List[Request]):
@@ -925,7 +957,6 @@ class ServingEngine:
                     self.state, jnp.int32(p), jnp.int32(newp))
                 self._slot_pages[i][j] = newp
                 self._table[i, j] = newp
-                self.metrics.on_cow()
         while extra > 0:
             got = self.pool.alloc(extra, req.tenant)
             if got is not None:
@@ -989,7 +1020,6 @@ class ServingEngine:
         req.prompt_pos = attached
         self.state = self._jit_attach(self.state, jnp.int32(i),
                                       jnp.int32(attached))
-        self.metrics.on_prefix(len(matched))
 
     def _register_prefix(self, i: int, req: Request):
         """Publish slot i's fully-prefilled PROMPT pages under their chain
@@ -1180,55 +1210,60 @@ class ServingEngine:
 
     # -- one engine tick ------------------------------------------------------
     def step(self):
-        # Completion flushing happens per pass (not only per poll) so a
-        # long-lived engine driven through the legacy try_admit()/step()
-        # path never accumulates finished Request objects.
-        self._just_finished = []
-        if self._has_deadlines:
-            if self.overlap:
-                self.sync()     # cancel only COMPLETE streams
-            self._expire_slots()
-            self._just_finished.extend(self._expire_queue())
-        if self.fault_plan is not None:
-            # Detect (and repair) faults from earlier ticks BEFORE this
-            # tick's injections land, so every fault is live for at least
-            # one pass — then inject whatever the plan schedules now.
-            if self.ticks % self.detect_every == 0 and (
-                    self._fault_dirty or self._lost_shard is not None):
-                self._detect_and_recover()
-            self._inject_due_faults()
-        live = [i for i, s in enumerate(self.slots) if s is not None]
-        if self.paged:
-            self._update_degraded()
-            if live:
-                # Claim/CoW/grow pages for every token this pass appends;
-                # pool exhaustion preempts here, before the jitted call.
-                live = self._ensure_pages(live)
-        if not live:
-            return
-        self.metrics.on_tick(self.now, len(live), self.capacity,
-                             self.scheduler.pending(self.now),
-                             pool=self.pool.stats() if self.paged else None,
-                             degraded=self._degraded)
-        prefilling = [i for i in live
-                      if self.slots[i].prompt_pos
-                      < len(self._feed(self.slots[i]))]
-        if self.chunked and prefilling:
-            if all(len(self._feed(self.slots[i])) - self.slots[i].prompt_pos
-                   == 1 for i in prefilling):
-                # Every prefilling slot has exactly ONE prompt token left:
-                # the decode tick already has the right shape, so feed that
-                # token as the decode input instead of paying a padded
-                # smallest-bucket chunk pass.
-                for i in prefilling:
-                    req = self.slots[i]
-                    self._set_next(i, self._feed(req)[req.prompt_pos])
-                    req.prompt_pos += 1
-                self._decode_tick()
+        with span("serving.step"):
+            # Completion flushing happens per pass (not only per poll) so
+            # a long-lived engine driven through the legacy
+            # try_admit()/step() path never accumulates finished Request
+            # objects.
+            self._just_finished = []
+            if self._has_deadlines:
+                if self.overlap:
+                    self.sync()     # cancel only COMPLETE streams
+                self._expire_slots()
+                self._just_finished.extend(self._expire_queue())
+            if self.fault_plan is not None:
+                # Detect (and repair) faults from earlier ticks BEFORE this
+                # tick's injections land, so every fault is live for at
+                # least one pass — then inject whatever the plan schedules
+                # now.
+                if self.ticks % self.detect_every == 0 and (
+                        self._fault_dirty or self._lost_shard is not None):
+                    self._detect_and_recover()
+                self._inject_due_faults()
+            live = [i for i, s in enumerate(self.slots) if s is not None]
+            if self.paged:
+                self._update_degraded()
+                if live:
+                    # Claim/CoW/grow pages for every token this pass
+                    # appends; pool exhaustion preempts here, before the
+                    # jitted call.
+                    live = self._ensure_pages(live)
+            if not live:
+                return
+            self.metrics.on_tick(
+                self.now, len(live), self.capacity,
+                self.scheduler.pending(self.now),
+                pool=self.pool.stats() if self.paged else None,
+                degraded=self._degraded)
+            prefilling = [i for i in live
+                          if self.slots[i].prompt_pos
+                          < len(self._feed(self.slots[i]))]
+            if self.chunked and prefilling:
+                if all(len(self._feed(self.slots[i]))
+                       - self.slots[i].prompt_pos == 1 for i in prefilling):
+                    # Every prefilling slot has exactly ONE prompt token
+                    # left: the decode tick already has the right shape, so
+                    # feed that token as the decode input instead of paying
+                    # a padded smallest-bucket chunk pass.
+                    for i in prefilling:
+                        req = self.slots[i]
+                        self._set_next(i, self._feed(req)[req.prompt_pos])
+                        req.prompt_pos += 1
+                    self._decode_tick()
+                else:
+                    self._prefill_pass(live)
             else:
-                self._prefill_pass(live)
-        else:
-            self._decode_tick()
+                self._decode_tick()
 
     def _prefill_pass(self, live: List[int]):
         """One bucketed prefill pass: prompt chunks for prefilling slots,
@@ -1247,57 +1282,81 @@ class ServingEngine:
             need[i] = min(rem, cap) if rem > 0 else 1
         bucket = next(c for c in self.prefill_chunks if c >= need.max())
 
-        tokens = np.zeros((self.capacity, bucket), np.int32)
-        riders = np.zeros((self.capacity,), bool)
-        for i in live:
-            req = self.slots[i]
-            toks = self._feed(req)
-            if req.prompt_pos < len(toks):
-                n = int(need[i])
-                tokens[i, :n] = toks[req.prompt_pos:req.prompt_pos + n]
-            elif (self.overlap and self._dev_next is not None
-                    and not self._ov_mask[i]):
-                riders[i] = True    # input = previous device sample
-            else:
-                tokens[i, 0] = self._next_input[i]
-        if self.paged:
-            # A private copy: JAX may read a host array after the call
-            # returns (on the CPU backend in place), and the host goes on
-            # editing the table for the passes it dispatches next.
-            self.state["page_table"] = jnp.asarray(self._table.copy())
-        temps, uids, idxs = self._samp_arrays()
-        self.key, sub = jax.random.split(self.key)
-        rv = (self._dev_next if self._dev_next is not None
-              else np.zeros((self.capacity,), np.int32))
-        args = (self.params, self.state, tokens, need, rv, riders, sub,
-                temps, uids, idxs)
-        t0 = self._perf()
-        self.metrics.window_open(t0)
-        (logits, sampled, self.state), warm = self._call(
-            ("prefill", bucket), args)
-        self._dev_next = sampled
-        self._ov_vals[:] = 0
-        self._ov_mask[:] = False
+        pass_id, fed = self.ticks, int(need.sum())
+        self.metrics.on_prefill(self.capacity * bucket, fed)
+        with span("serving.prefill_pass", pass_id=pass_id, bucket=bucket,
+                  rows=self.capacity * bucket, tokens=fed, live=len(live)):
+            tokens = np.zeros((self.capacity, bucket), np.int32)
+            riders = np.zeros((self.capacity,), bool)
+            for i in live:
+                req = self.slots[i]
+                toks = self._feed(req)
+                if req.prompt_pos < len(toks):
+                    n = int(need[i])
+                    tokens[i, :n] = toks[req.prompt_pos:req.prompt_pos + n]
+                elif (self.overlap and self._dev_next is not None
+                        and not self._ov_mask[i]):
+                    riders[i] = True    # input = previous device sample
+                else:
+                    tokens[i, 0] = self._next_input[i]
+            if self.paged:
+                # A private copy: JAX may read a host array after the call
+                # returns (on the CPU backend in place), and the host goes
+                # on editing the table for the passes it dispatches next.
+                self.state["page_table"] = jnp.asarray(self._table.copy())
+            temps, uids, idxs = self._samp_arrays()
+            self.key, sub = jax.random.split(self.key)
+            rv = (self._dev_next if self._dev_next is not None
+                  else np.zeros((self.capacity,), np.int32))
+            args = (self.params, self.state, tokens, need, rv, riders, sub,
+                    temps, uids, idxs)
+            t0 = self._perf()
+            self.metrics.window_open(t0)
+            (logits, sampled, self.state), warm = self._call(
+                ("prefill", bucket), args)
+            self._dev_next = sampled
+            self._ov_vals[:] = 0
+            self._ov_mask[:] = False
 
-        # Recipients: slots whose prompt completes this pass, or decode
-        # riders — exactly the slots _record would have sampled for.
-        recipients = [
-            i for i in live
-            if (len(self._feed(self.slots[i])) - self.slots[i].prompt_pos
-                <= int(need[i]))]
+            # Recipients: slots whose prompt completes this pass, or decode
+            # riders — exactly the slots _record would have sampled for.
+            recipients = [
+                i for i in live
+                if (len(self._feed(self.slots[i]))
+                    - self.slots[i].prompt_pos <= int(need[i]))]
 
-        if not self.overlap:
-            lg = None
-            if recipients:
-                lg = self._stream.fetch(logits, np.float32)  # host sync
-                done = self._perf()
-                self.metrics.on_device_span(t0, done)
-                if not warm:
-                    self.straggler.observe(done - t0)
+            if not self.overlap:
+                lg = None
+                if recipients:
+                    lg = self._stream.fetch(logits, np.float32,
+                                            pass_id=pass_id)  # host sync
+                    done = self._perf()
+                    self.metrics.on_device_span(t0, done)
+                    if not warm:
+                        self.straggler.observe(done - t0)
+                self._tick_clock()
+                if self.paged:
+                    for i in live:
+                        self._slot_len[i] += int(need[i])
+                for i in live:
+                    req = self.slots[i]
+                    toks = self._feed(req)
+                    if req.prompt_pos < len(toks):
+                        req.prompt_pos += int(need[i])
+                        if self.prefix_enabled:
+                            self._register_prefix(i, req)
+                        if req.prompt_pos < len(toks):
+                            continue    # still prefilling; logits unused
+                    # Prompt just completed (logits are at its last prompt
+                    # token) or the slot was decoding: sample either way.
+                    self._record(i, req, lg[i])
+                return
+
             self._tick_clock()
             if self.paged:
                 for i in live:
                     self._slot_len[i] += int(need[i])
+            recs: List[TokenRec] = []
             for i in live:
                 req = self.slots[i]
                 toks = self._feed(req)
@@ -1306,98 +1365,86 @@ class ServingEngine:
                     if self.prefix_enabled:
                         self._register_prefix(i, req)
                     if req.prompt_pos < len(toks):
-                        continue        # still prefilling; logits unused
-                # Prompt just completed (logits are at its last prompt
-                # token) or the slot was decoding: sample either way.
-                self._record(i, req, lg[i])
-            return
-
-        self._tick_clock()
-        if self.paged:
-            for i in live:
-                self._slot_len[i] += int(need[i])
-        recs: List[TokenRec] = []
-        for i in live:
-            req = self.slots[i]
-            toks = self._feed(req)
-            if req.prompt_pos < len(toks):
-                req.prompt_pos += int(need[i])
-                if self.prefix_enabled:
-                    self._register_prefix(i, req)
-                if req.prompt_pos < len(toks):
-                    continue
-            recs.append(self._account_dispatch(i, req))
-        self._stream.submit(Ticket(engine=self, t0=t0, warmup=warm,
-                                   sampled=sampled, recs=recs, now=self.now))
+                        continue
+                recs.append(self._account_dispatch(i, req))
+            self._stream.submit(Ticket(engine=self, t0=t0, warmup=warm,
+                                       sampled=sampled, recs=recs,
+                                       now=self.now, pass_id=pass_id))
 
     def _decode_tick(self):
-        if self.paged:
-            # A private copy: JAX may read a host array after the call
-            # returns (on the CPU backend in place), and the host goes on
-            # editing the table for the passes it dispatches next.
-            self.state["page_table"] = jnp.asarray(self._table.copy())
+        pass_id = self.ticks
         fed = [i for i, s in enumerate(self.slots) if s is not None]
-        # Host inputs go in as private copies, as the page table does.
-        token = (self._dev_next
-                 if self.overlap and self._dev_next is not None
-                 else self._next_input.copy())
-        ov_vals, ov_mask = self._ov_vals.copy(), self._ov_mask.copy()
-        temps, uids, idxs = self._samp_arrays()
-        self.key, sub = jax.random.split(self.key)
-        args = (self.params, self.state, token, ov_vals, ov_mask, sub,
-                temps, uids, idxs)
-        t0 = self._perf()
-        self.metrics.window_open(t0)
-        (logits, sampled, self.state), warm = self._call(("decode",), args)
-        self._dev_next = sampled
-        self._ov_vals[:] = 0
-        self._ov_mask[:] = False
+        with span("serving.decode_tick", pass_id=pass_id, live=len(fed)):
+            if self.paged:
+                # A private copy: JAX may read a host array after the call
+                # returns (on the CPU backend in place), and the host goes
+                # on editing the table for the passes it dispatches next.
+                self.state["page_table"] = jnp.asarray(self._table.copy())
+            # Host inputs go in as private copies, as the page table does.
+            token = (self._dev_next
+                     if self.overlap and self._dev_next is not None
+                     else self._next_input.copy())
+            ov_vals, ov_mask = self._ov_vals.copy(), self._ov_mask.copy()
+            temps, uids, idxs = self._samp_arrays()
+            self.key, sub = jax.random.split(self.key)
+            args = (self.params, self.state, token, ov_vals, ov_mask, sub,
+                    temps, uids, idxs)
+            t0 = self._perf()
+            self.metrics.window_open(t0)
+            (logits, sampled, self.state), warm = self._call(
+                ("decode",), args)
+            self._dev_next = sampled
+            self._ov_vals[:] = 0
+            self._ov_mask[:] = False
 
-        recipients = [i for i in fed
-                      if self.slots[i].prompt_pos
-                      >= len(self._feed(self.slots[i]))]
+            recipients = [i for i in fed
+                          if self.slots[i].prompt_pos
+                          >= len(self._feed(self.slots[i]))]
 
-        if not self.overlap:
-            lg = None
-            if recipients:
-                lg = self._stream.fetch(logits, np.float32)  # host sync
-                done = self._perf()
-                self.metrics.on_device_span(t0, done)
-                if not warm:
-                    self.straggler.observe(done - t0)
+            if not self.overlap:
+                lg = None
+                if recipients:
+                    lg = self._stream.fetch(logits, np.float32,
+                                            pass_id=pass_id)  # host sync
+                    done = self._perf()
+                    self.metrics.on_device_span(t0, done)
+                    if not warm:
+                        self.straggler.observe(done - t0)
+                self._tick_clock()
+                if self.paged:
+                    for i in fed:
+                        self._slot_len[i] += 1
+                for i, req in enumerate(self.slots):
+                    if req is None:
+                        continue
+                    toks = self._feed(req)
+                    if req.prompt_pos < len(toks):
+                        # legacy prefill-in-decode: feed the next prompt
+                        # token
+                        self._set_next(i, toks[req.prompt_pos])
+                        req.prompt_pos += 1
+                        continue
+                    self._record(i, req, lg[i])
+                return
+
             self._tick_clock()
             if self.paged:
                 for i in fed:
                     self._slot_len[i] += 1
-            for i, req in enumerate(self.slots):
+            recs: List[TokenRec] = []
+            for i in list(fed):
+                req = self.slots[i]
                 if req is None:
                     continue
                 toks = self._feed(req)
                 if req.prompt_pos < len(toks):
-                    # legacy prefill-in-decode: feed the next prompt token
                     self._set_next(i, toks[req.prompt_pos])
                     req.prompt_pos += 1
                     continue
-                self._record(i, req, lg[i])
-            return
-
-        self._tick_clock()
-        if self.paged:
-            for i in fed:
-                self._slot_len[i] += 1
-        recs: List[TokenRec] = []
-        for i in list(fed):
-            req = self.slots[i]
-            if req is None:
-                continue
-            toks = self._feed(req)
-            if req.prompt_pos < len(toks):
-                self._set_next(i, toks[req.prompt_pos])
-                req.prompt_pos += 1
-                continue
-            recs.append(self._account_dispatch(i, req))
-        self._stream.submit(Ticket(engine=self, t0=t0, warmup=warm,
-                                   sampled=sampled, recs=recs, now=self.now))
+                recs.append(self._account_dispatch(i, req))
+            self._stream.submit(Ticket(engine=self, t0=t0, warmup=warm,
+                                       sampled=sampled, recs=recs,
+                                       now=self.now, pass_id=pass_id))
 
     # -- open-loop API ----------------------------------------------------
     def poll(self) -> List[Request]:

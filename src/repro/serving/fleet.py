@@ -228,10 +228,13 @@ class FleetEngine(ServingEngine):
 
     def close(self) -> None:
         """Shut down the fleet's shared delivery worker (lanes never own
-        the stream in fleet mode, so this is the only close point)."""
+        the stream in fleet mode, so this is the only close point), then
+        close each lane."""
         if self._shared_stream is not None:
             self._shared_stream.sync()
             self._shared_stream.close()
+        for lane in self.lanes.values():
+            lane.close()
 
     # ``run()`` is inherited: submit-all + drain works unchanged because
     # both are overridden here.

@@ -12,10 +12,21 @@ Per request (``RequestMetrics``):
   * queue_delay — admit minus arrival (scheduler wait alone).
 
 Per fleet (``ServingMetrics``):
-  * tick utilization — live slots / capacity, sampled every jitted pass.
+  * utilization — live slots / capacity, sampled every jitted pass.
+  * tick utilization — host-clock device-busy share: the merged union of
+    [dispatch, delivery-done] spans over the time the engine had work.
   * queue depth — arrived-but-unadmitted requests, sampled every pass.
+  * prefill rows and tokens — rows the prefill passes ran (capacity x
+    bucket) against the real tokens fed in them; their ratio is the
+    padded share of prefill work.
   * percentile summaries (p50/p90/p99 by default) exported as JSON.
   * goodput — finished requests meeting a TTFT SLO, per clock unit.
+
+Spans (``span``): the engine and its delivery stream mark their host work
+as ``jax.profiler.TraceAnnotation``s named ``serving.*``, with integer
+args, and each garbage collection as ``python.gc`` (``GcSpans``).  They
+land in the profiler's own trace, on the clock of the device planes (the
+engine module docstring lists them).
 """
 
 from __future__ import annotations
@@ -26,6 +37,7 @@ from pathlib import Path
 from typing import Dict, Iterable, List, Optional, Sequence, Union
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 
 @dataclasses.dataclass
@@ -77,6 +89,31 @@ class RequestMetrics:
         return self.admit_time - self.arrival_time
 
 
+def span(name: str, **args: int) -> TraceAnnotation:
+    """A host span in the profiler's trace: ``with span("serving.step"):``.
+    ``args`` (ints) become the event's stats; ``set_metadata(**args)`` on
+    the open span adds more before it closes."""
+    return TraceAnnotation(name, **args)
+
+
+class GcSpans:
+    """A ``gc.callbacks`` hook that records each collection as a
+    ``python.gc`` span (arg ``generation``) on the collecting thread.  It
+    holds nothing but the open span, so a hook left registered keeps no
+    engine alive."""
+
+    def __init__(self) -> None:
+        self._open: Optional[TraceAnnotation] = None
+
+    def __call__(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            self._open = span("python.gc", generation=info["generation"])
+            self._open.__enter__()
+        elif self._open is not None:
+            self._open.__exit__(None, None, None)
+            self._open = None
+
+
 def percentile_summary(values: Iterable[Optional[float]],
                        percentiles: Sequence[int] = (50, 90, 99)) -> Dict:
     """``{"p50": ..., "p90": ..., "p99": ..., "mean": ..., "n": ...}`` over
@@ -117,6 +154,8 @@ class ServingMetrics:
                                     # prefix_hits / cow_copies / evictions)
         self.degraded_ticks = 0
         self.degraded_transitions = 0
+        self.prefill_rows = 0
+        self.prefill_tokens = 0
         # Fault-tolerance counters (serving.faults / engine recovery).
         self.faults: Dict[str, int] = {
             "injected": 0,
@@ -222,12 +261,12 @@ class ServingMetrics:
             uid=uid, tenant=tenant, rejected=True, shed=True,
             retry_after=retry_after)
 
-    def on_prefix(self, n_pages: int) -> None:
-        """``n_pages`` cached prompt pages attached instead of prefilled
-        (the cumulative pool-side counter lives in PoolStats)."""
-
-    def on_cow(self) -> None:
-        """One copy-on-write page split (cumulative count in PoolStats)."""
+    def on_prefill(self, rows: int, tokens: int) -> None:
+        """One prefill pass ran ``rows`` rows (capacity x bucket) for
+        ``tokens`` real tokens (prompt chunks, plus one per decoding slot
+        riding along)."""
+        self.prefill_rows += int(rows)
+        self.prefill_tokens += int(tokens)
 
     def on_degraded(self, entered: bool, now: float) -> None:
         self.degraded_transitions += 1
@@ -409,6 +448,12 @@ class ServingMetrics:
             "queue_delay": percentile_summary(
                 (r.queue_delay for r in fin), percentiles),
             "ticks": self.ticks,
+            "prefill": {
+                "rows": self.prefill_rows,
+                "tokens": self.prefill_tokens,
+                "pad_share": (1.0 - self.prefill_tokens / self.prefill_rows
+                              if self.prefill_rows else None),
+            },
             "tick_utilization": self.tick_utilization(),
             "utilization": {
                 "mean": float(np.mean(util)) if util else None,

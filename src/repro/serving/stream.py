@@ -21,6 +21,11 @@ flavors:
   queue (the engine calls it before anything that must see complete token
   streams: preemption replay snapshots, deadline expiry, fault requeues).
 
+Waits show in the profiler's trace: ``serving.fetch`` (arg ``pass_id``)
+around each device->host transfer, on the delivery worker when overlapped,
+and, overlapped only, ``serving.stream_wait`` wherever the engine thread
+blocks on the queue (a full ``submit``, or ``sync``).
+
 Worker exceptions are captured and re-raised on the next ``submit``/
 ``sync`` so a failing callback surfaces in the serve loop instead of dying
 silently on a daemon thread.
@@ -34,6 +39,8 @@ import threading
 from typing import Any, List, Optional
 
 import numpy as np
+
+from repro.serving.metrics import span
 
 
 @dataclasses.dataclass
@@ -58,6 +65,8 @@ class Ticket:
     sampled: Any                # (B,) int32 device array
     recs: List[TokenRec]
     now: float                  # engine clock at dispatch (token timestamps)
+    pass_id: int = -1           # the engine's pass counter at dispatch: ties
+                                # the pass's spans across threads
 
 
 class DeviceStream:
@@ -68,10 +77,12 @@ class DeviceStream:
     def __init__(self) -> None:
         self.host_syncs = 0
 
-    def fetch(self, arr, dtype=None) -> np.ndarray:
-        """Device -> host transfer (THE sync point)."""
+    def fetch(self, arr, dtype=None, *, pass_id: int) -> np.ndarray:
+        """Device -> host transfer (THE sync point) of pass ``pass_id``."""
         self.host_syncs += 1
-        return np.asarray(arr) if dtype is None else np.asarray(arr, dtype)
+        with span("serving.fetch", pass_id=pass_id):
+            return (np.asarray(arr) if dtype is None
+                    else np.asarray(arr, dtype))
 
     def submit(self, ticket: Ticket) -> None:
         ticket.engine._deliver_ticket(ticket)
@@ -125,14 +136,19 @@ class OverlappedStream(DeviceStream):
         self._raise_pending()
         if self._closed:
             raise RuntimeError("OverlappedStream is closed")
-        self._q.put(ticket)
+        try:
+            self._q.put_nowait(ticket)
+        except queue.Full:
+            with span("serving.stream_wait"):
+                self._q.put(ticket)
 
     def pending(self) -> int:
         return int(self._q.unfinished_tasks)
 
     def sync(self) -> None:
         """Block until every submitted ticket has been delivered."""
-        self._q.join()
+        with span("serving.stream_wait"):
+            self._q.join()
         self._raise_pending()
 
     def close(self) -> None:
