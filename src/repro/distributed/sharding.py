@@ -359,6 +359,11 @@ def decode_state_spec_tree(state: Pytree, mesh: Mesh) -> Pytree:
 
         if name in ("length", "position"):
             core = (dp,)
+        elif name == "kv_scale" or (name in ("k", "v")
+                                    and leaf.dtype == np.int8):
+            # int8 cache (B, KH, HD, S) and its scales (B, KH, 2, S)
+            core = (dp, MODEL_AXIS if shape[1] % mp == 0 else None,
+                    None, None)
         elif name in ("k", "v"):                   # (B, S, KH, HD)
             if shape[2] % mp == 0:
                 core = (dp, None, MODEL_AXIS, None)

@@ -4,7 +4,7 @@ The serving decode hot path was a CHAIN of dispatches per attention block —
 three separate ``abfp_matmul_packed_pallas`` launches for the Q/K/V
 projections, a jnp attention over the int8 KV cache, and a fourth launch for
 the output projection.  This module fuses the chain's front end into two
-Pallas kernels:
+Pallas kernels, and holds the int8 KV cache's one other writer:
 
 ``fused_qkv_packed_pallas``
     ONE weight-stationary launch over the three packed projection weights.
@@ -20,13 +20,19 @@ Pallas kernels:
     kernel launch instead of three.
 
 ``fused_quantized_decode_attention``
-    A (B,)-grid Pallas kernel computing decode attention directly on the
-    int8 KV codes, mirroring ``models.layers.quantized_decode_attention``
-    op-for-op.  A decode tick has a single query row, so the online-softmax
-    running max / denominator of ``flash_attention.py`` collapses to one
-    masked softmax over the whole (cache-resident) key axis; the kernel
-    keeps that degenerate form explicit so the scores/PV contractions and
-    the masking constant match the jnp reference bit-for-bit.
+    A (B, KH)-grid Pallas kernel that appends the tick's token to the int8
+    KV cache in place and computes decode attention directly on the codes,
+    mirroring ``models.layers.quantized_decode_attention`` op-for-op.  A
+    decode tick has a single query row, so the online-softmax running max /
+    denominator of ``flash_attention.py`` collapses to one masked softmax
+    over the whole (cache-resident) key axis; the kernel keeps that
+    degenerate form explicit so the scores/PV contractions and the masking
+    constant match the jnp reference bit-for-bit.
+
+``append_kv_columns``
+    The cache's other writer on one device (prefill chunks, the packed
+    chain's ticks): writes each slot's new positions in place, one
+    128-column tile at a time.
 
 Gain / amplification (the paper's headline knob) rides along: packed
 weights carry per-tile ADC gains (``PackedWeight.gains``, derived by
@@ -322,90 +328,257 @@ def fused_qkv_packed_pallas(
 # ---------------------------------------------------------------------------
 
 
-def _fused_attn_kernel(len_ref, q_ref, kc_ref, ks_ref, vc_ref, vs_ref,
-                       o_ref):
-    """Decode attention on int8 KV codes for one (batch element, KV head).
+def _tile(pos, w: int, s_max: int):
+    """The W-wide column tile that holds cache position ``pos``, clamped
+    to the cache's last tile."""
+    return jnp.minimum(pos // w, s_max // w - 1)
 
-    Mirrors ``models.layers.quantized_decode_attention`` op-for-op for the
-    ``rep`` query heads sharing this KV head: scores contract head_dim
-    against the raw int8 codes, the per-position scales factor out of both
-    contractions, masked positions get the same -1e30 the jnp path uses,
-    and the single query row makes the flash-attention online softmax
-    (``flash_attention.py``) degenerate to one ``jax.nn.softmax`` over the
-    key axis.
+
+def _fused_attn_kernel(layer_ref, len_ref, q_ref, kc_ref, vc_ref, sc_ref,
+                       nk_ref, nv_ref, ns_ref, o_ref, ko_ref, vo_ref, so_ref):
+    """Append one token and attend, on int8 KV codes, for one (batch
+    element, KV head).
+
+    The new token's codes and scales (``nk/nv/ns``, one column each) go in
+    at position ``len - 1``: into the cache blocks the attention reads, and
+    out through the aliased cache outputs, whose blocks are the W-wide
+    column tile that holds the position — the rest of the cache is not
+    written.  The attention mirrors ``models.layers
+    .quantized_decode_attention`` op-for-op for the ``rep`` query heads
+    sharing this KV head: scores contract head_dim against the raw int8
+    codes, the per-position scales factor out of both contractions, masked
+    positions get the same -1e30 the jnp path uses, and the single query
+    row makes the flash-attention online softmax (``flash_attention.py``)
+    degenerate to one ``jax.nn.softmax`` over the key axis.
     """
+    del layer_ref                            # used by the index maps only
     b = pl.program_id(0)
     d = q_ref.shape[-1]
-    s_max = kc_ref.shape[0]
+    s_max = kc_ref.shape[-1]
+    w = ko_ref.shape[-1]
     rep = q_ref.shape[0]
+    pos = len_ref[b] - 1
+    f32, i32 = jnp.float32, jnp.int32
 
-    qf = q_ref[...].astype(jnp.float32) * (d ** -0.5)           # (rep, d)
-    kc = kc_ref[...].astype(jnp.float32)                        # (s, d)
+    def append(col_ref, plane, out_ref, dtype):
+        """The plane with the new column, in f32; its W-wide tile that
+        holds ``pos`` to ``out_ref`` (a position past the end, which a free
+        slot's length reaches, matches no lane: the last tile goes back
+        unchanged)."""
+        col = col_ref[...].astype(f32)                          # (X, 1)
+        new = jax.lax.broadcasted_iota(i32, (1, s_max), 1) == pos
+        base = pl.multiple_of(_tile(pos, w, s_max) * w, w)
+        tile = plane[:, pl.ds(base, w)].astype(f32)
+        at = jax.lax.broadcasted_iota(i32, (1, w), 1) + base == pos
+        out_ref[...] = jnp.where(at, col, tile).astype(dtype)
+        return jnp.where(new, col, plane[...].astype(f32))
+
+    kc = append(nk_ref, kc_ref, ko_ref, jnp.int8)               # (d, s)
+    vc = append(nv_ref, vc_ref, vo_ref, jnp.int8)
+    sc = append(ns_ref, sc_ref, so_ref, sc_ref.dtype)           # (2, s)
+
+    qf = q_ref[...].astype(f32) * (d ** -0.5)                   # (rep, d)
     s = jax.lax.dot_general(
-        qf, kc, dimension_numbers=(((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)                     # (rep, s)
-    s = s * (ks_ref[...].astype(jnp.float32) / 127.0)           # (1, s)
-    pos = jax.lax.broadcasted_iota(jnp.int32, (rep, s_max), 1)
-    s = jnp.where(pos < len_ref[b], s, -1e30)
+        qf, kc, dimension_numbers=(((1,), (0,)), ((), ())),
+        preferred_element_type=f32)                             # (rep, s)
+    s = s * (sc[0:1] / 127.0)                                   # (1, s)
+    pos_s = jax.lax.broadcasted_iota(i32, (rep, s_max), 1)
+    s = jnp.where(pos_s < len_ref[b], s, -1e30)
     p = jax.nn.softmax(s, axis=-1)                              # (rep, s)
-    pv = p * (vs_ref[...].astype(jnp.float32) / 127.0)
+    pv = p * (sc[1:2] / 127.0)
     out = jax.lax.dot_general(
-        pv, vc_ref[...].astype(jnp.float32),
-        dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)                     # (rep, d)
+        pv, vc, dimension_numbers=(((1,), (1,)), ((), ())),
+        preferred_element_type=f32)                             # (rep, d)
     o_ref[...] = out.astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def fused_quantized_decode_attention(
     q: jax.Array,
-    k_codes: jax.Array, k_scale: jax.Array,
-    v_codes: jax.Array, v_scale: jax.Array,
+    k_codes: jax.Array,
+    v_codes: jax.Array,
+    scales: jax.Array,
+    new_k: jax.Array,
+    new_v: jax.Array,
+    new_scales: jax.Array,
     *,
     lengths: jax.Array,
+    layer: Optional[jax.Array] = None,
     interpret: Optional[bool] = None,
-) -> jax.Array:
-    """Pallas decode attention over the int8 KV cache, one grid cell per
+):
+    """Pallas decode tick over the int8 KV cache: append each slot's new
+    token at position ``lengths - 1``, then attend — one grid cell per
     (batch element, KV head).
 
-    Same signature and bit-identical output as
-    ``models.layers.quantized_decode_attention`` (enforced by
-    tests/test_fused.py); the cache is read once as int8 blocks instead of
-    traversing XLA's intermediate materializations of the batched einsum
-    chain.  ``q``: (B, 1, H, D); codes: (B, S, KH, D) int8; scales:
-    (B, S, KH); ``lengths``: (B,) int32 filled-slot counts.
+    ``q``: (B, 1, H, D); codes: (B, KH, D, S) int8; scales: (B, KH, 2, S),
+    K scales in row 0 and V scales in row 1; ``new_k``/``new_v``: (B, KH,
+    D) codes and ``new_scales``: (B, KH, 2) scales of the token to append;
+    ``lengths``: (B,) int32 filled-slot counts, the new token included.
+    Returns (out (B, 1, H, D), k_codes, v_codes, scales) with the token
+    appended.  The output is bit-identical to ``models.layers
+    .quantized_decode_attention`` on the appended cache (enforced by
+    tests/test_fused.py).
 
-    Each cell holds one head's (S, D) code planes in fast memory.  The TPU
-    tiles the two minor axes of a block: a cache row as stored, (S, KH, D),
-    pads KH up to a sublane tile and D up to 128 lanes, which at S = 2048 no
-    longer fits; and one of KH rows cannot be a block of it.  So the cache
-    is handed over head-major, (B, KH, S, D), a copy per call.
+    The cache is read where it lies: codes and scales may carry a leading
+    layer axis, (L, B, KH, D, S) and (L, B, KH, 2, S), and ``layer`` picks
+    the layer through the index maps (a scalar-prefetch argument), so the
+    decode step's scan over layers hands over its stacked cache as is.
+    Each cell DMAs one head's (D, S) code planes and (2, S) scale plane
+    into fast memory: blocks whose two minor axes are whole axes of the
+    array, which the TPU stores row-major, so no copy precedes the call.
+    The cache arrays are aliased to the outputs, and each cell writes back
+    only the 128-column tile that holds the new position (the whole
+    position axis when it is not a multiple of 128): the append is in
+    place, and the only writes to the cache in a decode tick, so XLA keeps
+    the cache in its row-major layout throughout.
     """
     if interpret is None:
         interpret = jax.default_backend() != "tpu"
+    stacked = k_codes.ndim == 5
+    if not stacked:                          # one layer's cache
+        k_codes, v_codes, scales = k_codes[None], v_codes[None], scales[None]
+        layer = 0
     b, _, h, d = q.shape
-    s_max, kh = k_codes.shape[1], k_codes.shape[2]
+    kh, s_max = k_codes.shape[2], k_codes.shape[4]
     rep = h // kh
-    kc = jnp.swapaxes(k_codes, 1, 2)
-    vc = jnp.swapaxes(v_codes, 1, 2)
-    # (S,) scale rows get a unit sublane axis, for the same reason.
-    ks = k_scale.transpose(0, 2, 1).reshape(b, kh, 1, s_max)
-    vs = v_scale.transpose(0, 2, 1).reshape(b, kh, 1, s_max)
-    head = pl.BlockSpec((None, None, rep, d), lambda i, g: (i, g, 0, 0))
-    codes = pl.BlockSpec((None, None, s_max, d), lambda i, g: (i, g, 0, 0))
-    scale = pl.BlockSpec((None, None, 1, s_max), lambda i, g: (i, g, 0, 0))
-    out = pl.pallas_call(
+    w = 128 if s_max % 128 == 0 else s_max
+
+    def plane(x):
+        return pl.BlockSpec((None, None, None, x, s_max),
+                            lambda i, g, l, n: (l[0], i, g, 0, 0))
+
+    def tile(x):
+        return pl.BlockSpec((None, None, None, x, w),
+                            lambda i, g, l, n: (l[0], i, g, 0,
+                                                _tile(n[i] - 1, w, s_max)))
+
+    def column(x):
+        return pl.BlockSpec((None, None, x, 1), lambda i, g, l, n: (i, g, 0, 0))
+
+    head = pl.BlockSpec((None, None, rep, d), lambda i, g, l, n: (i, g, 0, 0))
+    out, k_codes, v_codes, scales = pl.pallas_call(
         _fused_attn_kernel,
-        grid=(b, kh),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),         # lengths
-                  head, codes, scale, codes, scale],
-        out_specs=head,
-        out_shape=jax.ShapeDtypeStruct((b, kh, rep, d), q.dtype),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,                       # layer, lengths
+            grid=(b, kh),
+            in_specs=[head, plane(d), plane(d), plane(2),
+                      column(d), column(d), column(2)],
+            out_specs=[head, tile(d), tile(d), tile(2)]),
+        out_shape=[jax.ShapeDtypeStruct((b, kh, rep, d), q.dtype),
+                   jax.ShapeDtypeStruct(k_codes.shape, k_codes.dtype),
+                   jax.ShapeDtypeStruct(v_codes.shape, v_codes.dtype),
+                   jax.ShapeDtypeStruct(scales.shape, scales.dtype)],
+        input_output_aliases={3: 1, 4: 2, 5: 3},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")),
         interpret=interpret,
-    )(lengths.astype(jnp.int32), q.reshape(b, kh, rep, d), kc, ks, vc, vs)
-    return out.reshape(b, 1, h, d)
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), lengths.astype(jnp.int32),
+      q.reshape(b, kh, rep, d), k_codes, v_codes, scales,
+      new_k[..., None], new_v[..., None], new_scales[..., None])
+    if not stacked:
+        k_codes, v_codes, scales = k_codes[0], v_codes[0], scales[0]
+    return out.reshape(b, 1, h, d), k_codes, v_codes, scales
+
+
+def _append_kernel(layer_ref, tile_ref, k_ref, v_ref, s_ref, kw_ref, vw_ref,
+                   sw_ref, m_ref, ko_ref, vo_ref, so_ref, *, last: int):
+    """Write one slot's new columns into one W-wide column tile of each
+    cache plane: lanes the mask marks take the new column, the rest keep
+    what the tile holds."""
+    del layer_ref
+    b, t = pl.program_id(0), pl.program_id(1)
+    # Tiles past the cache's end are clamped onto its last tile; a step
+    # that lands on the tile the step before wrote leaves it as written.
+    revisit = jnp.logical_and(t > 0, tile_ref[b] + t > last)
+
+    @pl.when(jnp.logical_not(revisit))
+    def _write():
+        new = m_ref[...] != 0                                  # (1, W)
+        for old, win, out in ((k_ref, kw_ref, ko_ref), (v_ref, vw_ref, vo_ref),
+                              (s_ref, sw_ref, so_ref)):
+            out[...] = jnp.where(new, win[...].astype(jnp.float32),
+                                 old[...].astype(jnp.float32)
+                                 ).astype(out.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def append_kv_columns(
+    k_codes: jax.Array,
+    v_codes: jax.Array,
+    scales: jax.Array,
+    new_k: jax.Array,
+    new_v: jax.Array,
+    new_scales: jax.Array,
+    *,
+    start: jax.Array,
+    count: jax.Array,
+    layer: Optional[jax.Array] = None,
+    interpret: Optional[bool] = None,
+):
+    """Write C new positions per slot into the int8 KV cache, in place.
+
+    ``k_codes``/``v_codes``: ([L,] B, KH, D, S) int8 and ``scales``: ([L,]
+    B, KH, 2, S), at ``layer`` when stacked (the layout of
+    ``fused_quantized_decode_attention``); ``new_k``/``new_v``: (B, KH, D,
+    C) and ``new_scales``: (B, KH, 2, C), the columns of slot b going to
+    positions ``start[b] + [0, count[b])``; positions past S are dropped.
+    Returns the three arrays, aliased to the inputs.
+
+    One grid cell per (slot, W-wide column tile the slot's columns can
+    reach): it reads and writes back that tile only.  Because the cache is
+    written by Pallas calls alone, XLA keeps it in the row-major layout the
+    attention kernel reads (an XLA scatter into it would lay the whole
+    cache out again, and back, around every write).
+    """
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    stacked = k_codes.ndim == 5
+    if not stacked:
+        k_codes, v_codes, scales = k_codes[None], v_codes[None], scales[None]
+        layer = 0
+    _, b, kh, d, s_max = k_codes.shape
+    c = new_k.shape[-1]
+    w = 128 if s_max % 128 == 0 else s_max
+    n_tiles = 1 if w == s_max else -(-(c + w - 1) // w)
+    # The new columns laid out over the tiles they fall in: lane j of the
+    # window is position start // w * w + j.
+    tile0 = start.astype(jnp.int32) // w
+    lane = jnp.arange(n_tiles * w)[None, :]
+    col = tile0[:, None] * w + lane - start[:, None]
+    mask = (col >= 0) & (col < count[:, None]) & (
+        tile0[:, None] * w + lane < s_max)
+    idx = jnp.clip(col, 0, c - 1)[:, None, None, :]
+    wins = [jnp.take_along_axis(x, idx, axis=-1)
+            for x in (new_k, new_v, new_scales)]
+
+    def tile(x):
+        return pl.BlockSpec(
+            (None, None, kh, x, w),
+            lambda i, t, l, t0: (l[0], i, 0, 0,
+                                 jnp.minimum(t0[i] + t, s_max // w - 1)))
+
+    def window(x):
+        return pl.BlockSpec((None, kh, x, w), lambda i, t, l, t0: (i, 0, 0, t))
+
+    out = pl.pallas_call(
+        functools.partial(_append_kernel, last=s_max // w - 1),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,                       # layer, first tile
+            grid=(b, n_tiles),
+            in_specs=[tile(d), tile(d), tile(2), window(d), window(d),
+                      window(2),
+                      pl.BlockSpec((None, 1, w),
+                                   lambda i, t, l, t0: (i, 0, t))],
+            out_specs=[tile(d), tile(d), tile(2)]),
+        out_shape=[jax.ShapeDtypeStruct(a.shape, a.dtype)
+                   for a in (k_codes, v_codes, scales)],
+        input_output_aliases={2: 0, 3: 1, 4: 2},
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")),
+        interpret=interpret,
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), tile0, k_codes, v_codes,
+      scales, *wins, mask[:, None, :].astype(jnp.int32))
+    return tuple(out) if stacked else tuple(a[0] for a in out)
 
 
 # ---------------------------------------------------------------------------

@@ -28,6 +28,7 @@ from repro.models.lm import (  # noqa: F401
     encode_cross_kv,
     forward,
     forward_capture,
+    fused_decode_layers,
     init_decode_state,
     init_params,
     param_count,

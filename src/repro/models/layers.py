@@ -315,16 +315,78 @@ def _kv_encode(v: Array):
     return codes.astype(jnp.int8), s.astype(jnp.bfloat16)
 
 
-def _kv_decode(codes: Array, scales: Array, dtype) -> Array:
-    """(B, S, KH, D) int8 + (B, S, KH) scales -> dequantized cache."""
-    return (codes.astype(jnp.float32)
-            * (scales.astype(jnp.float32) / 127.0)[..., None]).astype(dtype)
+# The unpaged int8 cache is stored in the layout the decode attention kernel
+# reads: per (slot, KV head) a (D, S) code plane, position minor, and a
+# (2, S) scale plane holding the K (row 0) and V (row 1) scales —
+#   {"k": (B, KH, D, S) int8, "v": ..., "kv_scale": (B, KH, 2, S) bf16,
+#    "length": (B,)}.
+# The TPU keeps these row-major, so the kernel's (D, S) and (2, S) blocks
+# are DMA'd from where the cache lies.  (A (S, D) plane at D = 64 is stored
+# position-minor, and a (KH, S) scale plane slot-minor unless KH is a
+# multiple of 8: a kernel reading those gets a relayout copy per call.)
+# Inside the scan over layers the arrays carry a leading layer axis and the
+# dict a "layer" index: writes land in that layer's slice in place, reads
+# take it where it lies.  On one device only Pallas kernels write the cache
+# (``_q_write``, and the fused decode kernel): an XLA scatter into it makes
+# XLA lay the whole cache out again, and back, around every write.
+
+
+def _q_planes(kv_cache: dict):
+    """The (k, v, kv_scale) planes of the cache's layer: (B, KH, D, S) x 2
+    and (B, KH, 2, S)."""
+    planes = (kv_cache["k"], kv_cache["v"], kv_cache["kv_scale"])
+    if "layer" not in kv_cache:
+        return planes
+    return tuple(a[kv_cache["layer"]] for a in planes)
+
+
+def _put(buf: Array, idx: tuple, vals: Array,
+         valid: Optional[Array] = None) -> Array:
+    """``buf.at[idx].set(vals)`` where ``idx[-1]`` holds the positions.
+
+    Lanes with ``valid`` False (padding) and lanes past the buffer's end
+    are dropped, so they leave what is there bit-for-bit."""
+    if valid is not None:
+        idx = idx[:-1] + (jnp.where(valid, idx[-1], buf.shape[len(idx) - 1]),)
+    return buf.at[idx].set(vals.astype(buf.dtype), mode="drop")
+
+
+def _q_write(kv_cache: dict, start: Array, count: Array, k: Array,
+             v: Array, pallas: bool) -> dict:
+    """Encode k, v (B, C, KH, D) and write their codes and scales at
+    positions ``start + [0, count)`` of each slot — in place: only the new
+    positions are written, positions past the cache's end are dropped.
+
+    ``pallas`` (one device): by the Pallas append kernel, so that XLA keeps
+    the cache in the layout the attention kernel reads; else (under a mesh,
+    where GSPMD partitions it) by an XLA scatter."""
+    kc, ks = _kv_encode(k)
+    vc, vs = _kv_encode(v)
+    sc = jnp.stack([ks, vs], -1)                        # (B, C, KH, 2)
+    names = ("k", "v", "kv_scale")
+    if pallas:
+        from repro.kernels.abfp_decode_fused import append_kv_columns
+        planes = append_kv_columns(
+            *(kv_cache[n] for n in names),
+            *(jnp.moveaxis(x, 1, -1) for x in (kc, vc, sc)),
+            start=start, count=count, layer=kv_cache.get("layer"))
+    else:
+        b, c = k.shape[:2]
+        offs = jnp.arange(c)[None, :]
+        at = ((kv_cache["layer"],) if "layer" in kv_cache else ()) + (
+            jnp.arange(b)[:, None], slice(None), slice(None),
+            start[:, None] + offs)
+        valid = offs < count[:, None]
+        planes = [_put(kv_cache[n], at, x, valid)
+                  for n, x in zip(names, (kc, vc, sc))]
+    return {**kv_cache, **dict(zip(names, planes))}
 
 
 def quantized_decode_attention(
     q: Array,
-    k_codes: Array, k_scale: Array,
-    v_codes: Array, v_scale: Array,
+    k_codes: Array,
+    v_codes: Array,
+    scales: Array,
     *,
     lengths: Array,
 ) -> Array:
@@ -337,25 +399,35 @@ def quantized_decode_attention(
     so the cache is read ONCE as int8 (+ tiny scale vectors) instead of
     int8-read + bf16-write + bf16-read of a dequantized copy.  Same math as
     dequantize-then-attend up to f32 rounding.
+
+    q: (B, 1, H, D); codes: (B, KH, D, S) int8; scales: (B, KH, 2, S), the
+    K scales in row 0 and the V scales in row 1.
     """
     b, _, h, d = q.shape
-    s_max = k_codes.shape[1]
-    kh = k_codes.shape[2]
+    kh = k_codes.shape[1]
+    s_max = k_codes.shape[3]
     rep = h // kh
     qf = q.astype(jnp.float32) * (d ** -0.5)                 # (B, 1, H, D)
     qg = qf.reshape(b, kh, rep, d)                            # group by KV head
-    kc = k_codes.astype(jnp.float32)                          # int8 -> f32 codes
-    # codes layout (B, S, KH, D): contract D per kv head
-    s = jnp.einsum("bgrd,bsgd->bgrs", qg, kc)                 # (B, KH, rep, S)
-    s = s * (k_scale.astype(jnp.float32).transpose(0, 2, 1)[:, :, None, :]
-             / 127.0)
+    s = _codes_dot(qg, k_codes, 2)                            # (B, KH, rep, S)
+    s = s * (scales[:, :, 0:1].astype(jnp.float32) / 127.0)
     pos = jnp.arange(s_max)[None, None, None, :]
     s = jnp.where(pos < lengths[:, None, None, None], s, -1e30)
     p = jax.nn.softmax(s, axis=-1)                            # (B, KH, rep, S)
-    pv = p * (v_scale.astype(jnp.float32).transpose(0, 2, 1)[:, :, None, :]
-              / 127.0)
-    out = jnp.einsum("bgrs,bsgd->bgrd", pv, v_codes.astype(jnp.float32))
+    pv = p * (scales[:, :, 1:2].astype(jnp.float32) / 127.0)
+    out = _codes_dot(pv, v_codes, 3)                          # (B, KH, rep, D)
     return out.reshape(b, 1, h, d).astype(q.dtype)
+
+
+def _codes_dot(x: Array, codes: Array, axis: int) -> Array:
+    """Per (slot, KV head), the rows of x (B, KH, M, .) times the f32 codes
+    (B, KH, D, S), contracting the codes' ``axis``: D for the scores, S
+    for the values.  The decode and chunk readers, and the kernel, contract
+    their rows in this one form, so a chunk's query rows round exactly as
+    the decode ticks that would have produced them."""
+    return jax.lax.dot_general(
+        x, codes.astype(jnp.float32),
+        dimension_numbers=(((3,), (axis,)), ((0, 1), (0, 1))))
 
 
 # ---------------------------------------------------------------------------
@@ -389,82 +461,98 @@ def chunk_cache_attention(q: Array, k_cache: Array, v_cache: Array,
 
 def quantized_chunk_attention(
     q: Array,
-    k_codes: Array, k_scale: Array,
-    v_codes: Array, v_scale: Array,
+    k_codes: Array,
+    v_codes: Array,
+    scales: Array,
     *,
     q_pos: Array,
 ) -> Array:
     """Chunked-prefill attention directly on int8 KV codes — the S-query
-    generalization of ``quantized_decode_attention`` (same per-position
-    scale factoring, same einsum layout per query row)."""
+    generalization of ``quantized_decode_attention`` (same cache layout,
+    same per-position scale factoring, same einsum layout per query row)."""
     b, s, h, d = q.shape
-    s_max = k_codes.shape[1]
-    kh = k_codes.shape[2]
+    kh = k_codes.shape[1]
+    s_max = k_codes.shape[3]
     rep = h // kh
     qf = q.astype(jnp.float32) * (d ** -0.5)                 # (B, S, H, D)
-    qg = qf.reshape(b, s, kh, rep, d)
-    kc = k_codes.astype(jnp.float32)
-    sc = jnp.einsum("bsgrd,bcgd->bgrsc", qg, kc)             # (B, KH, rep, S, C)
-    sc = sc * (k_scale.astype(jnp.float32).transpose(0, 2, 1)
-               [:, :, None, None, :] / 127.0)
+    qg = jnp.moveaxis(qf.reshape(b, s, kh, rep, d), 1, 3)    # (B, KH, rep, S, D)
+    sc = _codes_dot(qg.reshape(b, kh, rep * s, d), k_codes, 2)
+    sc = sc.reshape(b, kh, rep, s, s_max)                    # (B, KH, rep, S, C)
+    sc = sc * (scales[:, :, 0, None, None, :].astype(jnp.float32) / 127.0)
     mask = (jnp.arange(s_max)[None, None, :]
             <= q_pos[:, :, None])                            # (B, S, C)
     sc = jnp.where(mask[:, None, None], sc, -1e30)
     p = jax.nn.softmax(sc, axis=-1)                          # (B, KH, rep, S, C)
-    pv = p * (v_scale.astype(jnp.float32).transpose(0, 2, 1)
-              [:, :, None, None, :] / 127.0)
-    out = jnp.einsum("bgrsc,bcgd->bsgrd", pv, v_codes.astype(jnp.float32))
+    pv = p * (scales[:, :, 1, None, None, :].astype(jnp.float32) / 127.0)
+    out = _codes_dot(pv.reshape(b, kh, rep * s, s_max), v_codes, 3)
+    out = jnp.moveaxis(out.reshape(b, kh, rep, s, d), 3, 1)  # (B, S, KH, rep, D)
     return out.reshape(b, s, h, d).astype(q.dtype)
 
 
 def _append_attend_one(q: Array, k: Array, v: Array, kv_cache: dict,
-                       window: int):
+                       window: int, valid: Optional[Array] = None, *,
+                       pallas: bool = False, fused: bool = False):
     """Append ONE token's K/V and attend — the decode-tick attention core.
 
     q: (B, 1, H, D); k, v: (B, 1, KH, D).  Shared by the S=1 decode path and
     the ring-buffer chunk scan, so both run the same ops (bit-identical by
-    construction).  Returns (out (B, 1, H, D), new_cache).
+    construction).  ``valid`` (B,) False leaves a slot's cache and length
+    as they were (the chunk scan's padding lanes).  ``pallas``: one device,
+    so a quantized cache is written by the Pallas append kernel
+    (``_q_write``); ``fused`` appends and attends in one Pallas kernel,
+    which reads and writes the cache where it lies
+    (``_fused_decode_attention_block``, window 0).  Returns (out (B, 1, H,
+    D), new_cache).
     """
     b = q.shape[0]
-    s_max = kv_cache["k"].shape[1]
+    quantized = "kv_scale" in kv_cache
+    s_max = kv_cache["k"].shape[-1 if quantized else 1]
     length = kv_cache["length"]                         # (B,)
     slot = (length % s_max) if window > 0 else length   # ring for window
     bidx = jnp.arange(b)
-    quantized = "k_scale" in kv_cache
     filled = jnp.minimum(length + 1, s_max) if window > 0 else length + 1
     if quantized:
         # ABFP-quantized cache (beyond-paper, DESIGN.md): int8 codes +
         # per-(token, head) max-abs scale over the head_dim vector.
         # Attention runs directly on the codes (no dequantized copy).
-        kc, ks = _kv_encode(k[:, 0])
-        vc, vs = _kv_encode(v[:, 0])
-        k_cache = kv_cache["k"].at[bidx, slot].set(kc)
-        v_cache = kv_cache["v"].at[bidx, slot].set(vc)
-        k_scale = kv_cache["k_scale"].at[bidx, slot].set(ks)
-        v_scale = kv_cache["v_scale"].at[bidx, slot].set(vs)
-        out = quantized_decode_attention(
-            q, k_cache, k_scale, v_cache, v_scale, lengths=filled)
-        new_cache = {"k": k_cache, "v": v_cache, "length": length + 1,
-                     "k_scale": k_scale, "v_scale": v_scale}
+        if fused:
+            from repro.kernels.abfp_decode_fused import (
+                fused_quantized_decode_attention,
+            )
+            (kc, ks), (vc, vs) = _kv_encode(k[:, 0]), _kv_encode(v[:, 0])
+            out, kcache, vcache, scales = fused_quantized_decode_attention(
+                q, kv_cache["k"], kv_cache["v"], kv_cache["kv_scale"],
+                kc, vc, jnp.stack([ks, vs], -1), lengths=filled,
+                layer=kv_cache.get("layer"))
+            new_cache = {**kv_cache, "k": kcache, "v": vcache,
+                         "kv_scale": scales}
+        else:
+            count = (jnp.ones_like(length) if valid is None
+                     else valid.astype(length.dtype))
+            new_cache = _q_write(kv_cache, slot, count, k, v, pallas)
+            out = quantized_decode_attention(q, *_q_planes(new_cache),
+                                             lengths=filled)
     else:
-        k_cache = kv_cache["k"].at[bidx, slot].set(
-            k[:, 0].astype(kv_cache["k"].dtype))
-        v_cache = kv_cache["v"].at[bidx, slot].set(
-            v[:, 0].astype(kv_cache["v"].dtype))
-        out = decode_attention(q, k_cache, v_cache, lengths=filled)
-        new_cache = {"k": k_cache, "v": v_cache, "length": length + 1}
+        new_cache = {
+            "k": _put(kv_cache["k"], (bidx, slot), k[:, 0], valid),
+            "v": _put(kv_cache["v"], (bidx, slot), v[:, 0], valid)}
+        out = decode_attention(q, new_cache["k"], new_cache["v"],
+                               lengths=filled)
+    new_cache["length"] = length + (1 if valid is None
+                                    else valid.astype(length.dtype))
     return out, new_cache
 
 
 def chunk_append_attend(q: Array, k: Array, v: Array, kv_cache: dict,
-                        *, n_tokens: Array, window: int):
+                        *, n_tokens: Array, window: int,
+                        pallas: bool = False):
     """Append up to S new K/V per slot and attend all S chunk queries — the
     chunked-prefill attention core.
 
     q: (B, S, H, D); k, v: (B, S, KH, D); ``n_tokens``: (B,) int32 — tokens
     0..n-1 of slot b's chunk are real, the rest padding.  A slot with
     n_tokens == 0 keeps its cache slice bit-for-bit unchanged (padding lanes
-    write back the values already in their slots).
+    write nothing).  ``pallas``: as for ``_append_attend_one``.
 
     Two regimes:
       * window == 0 (append-only cache): scatter the chunk, then one batched
@@ -489,54 +577,31 @@ def chunk_append_attend(q: Array, k: Array, v: Array, kv_cache: dict,
 
         def step(cache, xs):
             q_t, k_t, v_t, ok = xs
-            out_t, new_cache = _append_attend_one(q_t, k_t, v_t, cache, window)
-            sel = lambda new, old: jnp.where(  # noqa: E731
-                ok.reshape((b,) + (1,) * (new.ndim - 1)), new, old)
-            return jax.tree.map(sel, new_cache, cache), out_t[:, 0]
+            out_t, new_cache = _append_attend_one(q_t, k_t, v_t, cache,
+                                                  window, ok, pallas=pallas)
+            return new_cache, out_t[:, 0]
 
         new_cache, outs = jax.lax.scan(step, kv_cache, (qs, ks, vs, valid))
         return jnp.moveaxis(outs, 0, 1), new_cache
 
     length = kv_cache["length"]                             # (B,)
-    s_max = kv_cache["k"].shape[1]
     offs = jnp.arange(s)[None, :]
     valid = offs < n_tokens[:, None]                        # (B, S)
-    # Padding lanes collapse onto the slot just past the last real token
-    # (the next position a later chunk/tick will overwrite) and write back
-    # the value already there — untouched slots stay bit-identical.  When
-    # length + n_tokens == S_max that slot does not exist: those lanes go
-    # out of bounds and are DROPPED (scatter mode="drop") instead of being
-    # clamped onto index S_max - 1, where they would collide with the last
-    # real token's write and could silently win the duplicate-index race.
-    idx = length[:, None] + jnp.minimum(offs, n_tokens[:, None])
+    # Padding lanes are DROPPED (sent past the cache's end, scatter
+    # mode="drop"): untouched slots stay bit-identical, and no padding lane
+    # can land on a real token's position and win a duplicate-index race.
     bidx = jnp.arange(b)[:, None]
-
-    def scatter(buf, new_vals):
-        old = buf[bidx, idx]        # OOB reads clamp; those lanes are dropped
-        sel = valid.reshape(valid.shape + (1,) * (new_vals.ndim - 2))
-        return buf.at[bidx, idx].set(
-            jnp.where(sel, new_vals.astype(buf.dtype), old), mode="drop")
-
     q_pos = length[:, None] + offs                          # (B, S) global
-    quantized = "k_scale" in kv_cache
-    if quantized:
-        kc, ks = _kv_encode(k)                              # (B,S,KH,D)/(B,S,KH)
-        vc, vs = _kv_encode(v)
-        k_cache = scatter(kv_cache["k"], kc)
-        v_cache = scatter(kv_cache["v"], vc)
-        k_scale = scatter(kv_cache["k_scale"], ks)
-        v_scale = scatter(kv_cache["v_scale"], vs)
-        out = quantized_chunk_attention(
-            q, k_cache, k_scale, v_cache, v_scale, q_pos=q_pos)
-        new_cache = {"k": k_cache, "v": v_cache,
-                     "length": length + n_tokens,
-                     "k_scale": k_scale, "v_scale": v_scale}
+    if "kv_scale" in kv_cache:
+        new_cache = _q_write(kv_cache, length, n_tokens, k, v, pallas)
+        out = quantized_chunk_attention(q, *_q_planes(new_cache),
+                                        q_pos=q_pos)
     else:
-        k_cache = scatter(kv_cache["k"], k)
-        v_cache = scatter(kv_cache["v"], v)
-        out = chunk_cache_attention(q, k_cache, v_cache, q_pos=q_pos)
-        new_cache = {"k": k_cache, "v": v_cache,
-                     "length": length + n_tokens}
+        new_cache = {"k": _put(kv_cache["k"], (bidx, q_pos), k, valid),
+                     "v": _put(kv_cache["v"], (bidx, q_pos), v, valid)}
+        out = chunk_cache_attention(q, new_cache["k"], new_cache["v"],
+                                    q_pos=q_pos)
+    new_cache["length"] = length + n_tokens
     return out, new_cache
 
 
@@ -628,16 +693,17 @@ def paged_append_attend(q: Array, k: Array, v: Array, kv_cache: dict,
         vsp = _paged_scatter(kv_cache["v_scale_pages"], table, pos, vs, valid)
         new_cache = {"k_pages": kp, "v_pages": vp, "k_scale_pages": ksp,
                      "v_scale_pages": vsp, "length": length + n_add}
+        # The dense view, in the unpaged cache's layout for the readers:
+        # (B, KH, D, S) codes, (B, KH, 2, S) scales.
+        codes = [jnp.transpose(_paged_view(a, table), (0, 2, 3, 1))
+                 for a in (kp, vp)]
+        scales = jnp.stack([jnp.swapaxes(_paged_view(a, table), 1, 2)
+                            for a in (ksp, vsp)], axis=2)
         if decode:
-            out = quantized_decode_attention(
-                q, _paged_view(kp, table), _paged_view(ksp, table),
-                _paged_view(vp, table), _paged_view(vsp, table),
-                lengths=length + 1)
+            out = quantized_decode_attention(q, *codes, scales,
+                                             lengths=length + 1)
         else:
-            out = quantized_chunk_attention(
-                q, _paged_view(kp, table), _paged_view(ksp, table),
-                _paged_view(vp, table), _paged_view(vsp, table),
-                q_pos=q_pos)
+            out = quantized_chunk_attention(q, *codes, scales, q_pos=q_pos)
     else:
         kp = _paged_scatter(kv_cache["k_pages"], table, pos, k, valid)
         vp = _paged_scatter(kv_cache["v_pages"], table, pos, v, valid)
@@ -688,10 +754,7 @@ def _fused_decode_attention_block(params, x, mcfg, nx, *, positions,
     the counter identically, so the wo projection (and every later layer)
     sees an unchanged stream.
     """
-    from repro.kernels.abfp_decode_fused import (
-        fused_qkv_dense,
-        fused_quantized_decode_attention,
-    )
+    from repro.kernels.abfp_decode_fused import fused_qkv_dense
 
     b, s, _ = x.shape
     h, kh, hd = mcfg.num_heads, mcfg.num_kv_heads, mcfg.resolved_head_dim
@@ -713,27 +776,12 @@ def _fused_decode_attention_block(params, x, mcfg, nx, *, positions,
         q = rope(q, positions, mcfg.rope_theta, mcfg.rope_fraction)
         k = rope(k, positions, mcfg.rope_theta, mcfg.rope_fraction)
 
-    # ``_append_attend_one``'s quantized branch (window == 0: slot ==
-    # length), with the attention einsum chain swapped for the Pallas
-    # kernel.  Under a mesh the jnp form runs instead: it is bit-identical
-    # to the kernel (enforced by test) and partitions under GSPMD, which a
-    # pallas_call does not.
-    length = kv_cache["length"]
-    bidx = jnp.arange(b)
-    kc, ks = _kv_encode(k[:, 0])
-    vc, vs = _kv_encode(v[:, 0])
-    k_cache = kv_cache["k"].at[bidx, length].set(kc)
-    v_cache = kv_cache["v"].at[bidx, length].set(vc)
-    k_scale = kv_cache["k_scale"].at[bidx, length].set(ks)
-    v_scale = kv_cache["v_scale"].at[bidx, length].set(vs)
-    if nx.mesh is None:
-        out = fused_quantized_decode_attention(
-            q, k_cache, k_scale, v_cache, v_scale, lengths=length + 1)
-    else:
-        out = quantized_decode_attention(
-            q, k_cache, k_scale, v_cache, v_scale, lengths=length + 1)
-    new_cache = {"k": k_cache, "v": v_cache, "length": length + 1,
-                 "k_scale": k_scale, "v_scale": v_scale}
+    # ``_append_attend_one``'s quantized branch, with the attention einsum
+    # chain swapped for the Pallas kernel.  Under a mesh the jnp form runs
+    # instead: it is bit-identical to the kernel (enforced by test) and
+    # partitions under GSPMD, which a pallas_call does not.
+    out, new_cache = _append_attend_one(q, k, v, kv_cache, 0,
+                                        fused=nx.mesh is None)
     return nx.dense(out.reshape(b, s, h * hd), params["wo"]), new_cache
 
 
@@ -749,7 +797,7 @@ def _use_fused_decode(params, nx, s, kv_cache, cross_kv, window, n_tokens):
     return (nx.quant.mode == "abfp_fused"
             and s == 1 and n_tokens is None and window == 0
             and kv_cache is not None and cross_kv is None
-            and "k_pages" not in kv_cache and "k_scale" in kv_cache
+            and "kv_scale" in kv_cache
             and all(isinstance(params[w], PackedWeight)
                     for w in ("wq", "wk", "wv")))
 
@@ -772,7 +820,9 @@ def attention_block(
     """Self- (or cross-) attention with optional KV cache for decode.
 
     Returns (output, new_kv_cache).  ``kv_cache``: {"k": (B,S,KH,D),
-    "v": ..., "length": (B,)} — ring buffer when window > 0.
+    "v": ..., "length": (B,)} — ring buffer when window > 0 — or the int8
+    cache (``_q_write``'s layout), whose arrays may be stacked over layers
+    with a "layer" index.
     ``train_mode`` selects the q-chunked remat attention (backward-memory
     bounded); inference uses the kv-chunked online-softmax path.
 
@@ -812,13 +862,15 @@ def attention_block(
     elif kv_cache is not None and cross_kv is None:
         if s == 1 and n_tokens is None:
             # Decode: append this step's K/V, attend over the filled cache.
-            out, new_cache = _append_attend_one(q, k, v, kv_cache, window)
+            out, new_cache = _append_attend_one(
+                q, k, v, kv_cache, window, pallas=nx.mesh is None)
         else:
             # Chunked prefill: append + attend a whole prompt chunk.
             n = (n_tokens if n_tokens is not None
                  else jnp.full((b,), s, jnp.int32))
             out, new_cache = chunk_append_attend(
-                q, k, v, kv_cache, n_tokens=n, window=window)
+                q, k, v, kv_cache, n_tokens=n, window=window,
+                pallas=nx.mesh is None)
     elif cross_kv is not None:
         if train_mode:
             out = train_attention(q, k, v, causal=False,

@@ -33,6 +33,7 @@ from repro.models import moe as moe_lib
 from repro.models import recurrent as rec_lib
 from repro.models.layers import (
     Numerics,
+    _use_fused_decode,
     attention_block,
     init_attention,
     init_mlp,
@@ -435,12 +436,14 @@ def init_decode_state(mcfg: ModelConfig, batch: int, max_len: int, *,
                     "length": jnp.zeros((batch,), jnp.int32),
                 }}
             if mcfg.kv_quant:
-                # ABFP-quantized cache: int8 codes + per-(token, head) scale.
+                # ABFP-quantized cache: int8 codes + per-(token, head)
+                # scale, in the layout the decode attention kernel reads
+                # (models.layers._q_write).
                 return {"kv": {
-                    "k": jnp.zeros((batch, cache_len, kh, hd), jnp.int8),
-                    "v": jnp.zeros((batch, cache_len, kh, hd), jnp.int8),
-                    "k_scale": jnp.zeros((batch, cache_len, kh), jnp.bfloat16),
-                    "v_scale": jnp.zeros((batch, cache_len, kh), jnp.bfloat16),
+                    "k": jnp.zeros((batch, kh, hd, cache_len), jnp.int8),
+                    "v": jnp.zeros((batch, kh, hd, cache_len), jnp.int8),
+                    "kv_scale": jnp.zeros((batch, kh, 2, cache_len),
+                                          jnp.bfloat16),
                     "length": jnp.zeros((batch,), jnp.int32),
                 }}
             return {"kv": {
@@ -488,6 +491,92 @@ def init_decode_state(mcfg: ModelConfig, batch: int, max_len: int, *,
     return state
 
 
+def _carried_kv(layer_state) -> Optional[dict]:
+    """The layer's int8 K/V cache, if it has one (``kv_scale``: unpaged)."""
+    kv = (layer_state or {}).get("kv")
+    return kv if kv is not None and "kv_scale" in kv else None
+
+
+def _run_layers(params, state, x, mcfg: ModelConfig, nx: Numerics, *,
+                positions, enc_kv=None, n_tokens=None):
+    """Every layer of a decode tick or prefill chunk, with carried state.
+
+    The scan over layer groups takes each layer's state as ``xs`` and gives
+    it back as ``ys`` — except the int8 K/V caches, which ride in the scan's
+    carry, stacked over groups: each layer writes its new positions into
+    its own slice in place (``models.layers._q_write``) and its attention
+    reads the slice where it lies, so no layer's cache is sliced out and
+    stacked back.  Returns (x, group states, extra-layer states)."""
+    pattern, n_groups, remainder = _pattern(mcfg)
+    glen = len(pattern)
+    pt = state.get("page_table")
+    kvs = tuple(_carried_kv(st) for st in state["groups"])
+    rest = tuple({} if kv is not None else st
+                 for st, kv in zip(state["groups"], kvs))
+
+    def body(carry, xs):
+        x, kvs = carry
+        gparams, gstate, g_enc_kv, g = xs
+        new_kvs, new_states = [], []
+        for j, kind in enumerate(pattern):
+            st = gstate[j]
+            if kvs[j] is not None:
+                st = {"kv": {**kvs[j], "length": kvs[j]["length"][g],
+                             "layer": g}}
+            ek = g_enc_kv[j] if g_enc_kv is not None else None
+            x, st, _ = _apply_layer(
+                gparams[j], x, mcfg, kind, nx.fold(g * glen + j),
+                positions=positions, state=st, enc_kv=ek,
+                n_tokens=n_tokens, page_table=pt)
+            if kvs[j] is not None:
+                kv = st["kv"]
+                new_kvs.append({"k": kv["k"], "v": kv["v"],
+                                "kv_scale": kv["kv_scale"],
+                                "length": kvs[j]["length"].at[g].set(
+                                    kv["length"])})
+                st = {}
+            else:
+                new_kvs.append(None)
+            new_states.append(st)
+        return (x, tuple(new_kvs)), tuple(new_states)
+
+    (x, kvs), states = jax.lax.scan(
+        body, (x, kvs),
+        (params["groups"], rest, enc_kv, jnp.arange(n_groups)))
+    groups = tuple(st if kv is None else {"kv": kv}
+                   for st, kv in zip(states, kvs))
+
+    extra = []
+    for r in range(remainder):
+        x, st, _ = _apply_layer(
+            params["extra"][r], x, mcfg, pattern[r],
+            nx.fold(n_groups * glen + r), positions=positions,
+            state=state["extra"][r], enc_kv=None, n_tokens=n_tokens,
+            page_table=pt)
+        extra.append(st)
+    return x, groups, tuple(extra)
+
+
+def fused_decode_layers(params, state, mcfg: ModelConfig,
+                        nx: Numerics) -> int:
+    """How many layers' decode ticks run the fused attention kernel on the
+    cache where it lies: ``models.layers._use_fused_decode`` per layer, on
+    one device (under a mesh the jnp attention runs instead)."""
+    if nx.mesh is not None:
+        return 0
+    pattern, n_groups, remainder = _pattern(mcfg)
+    window = mcfg.window_size if mcfg.attention_type == "hybrid" else 0
+
+    def fused(lp, st, kind):
+        return kind == "attention" and _use_fused_decode(
+            lp["attn"], nx, 1, (st or {}).get("kv"), None, window, None)
+
+    return (n_groups * sum(fused(params["groups"][j], state["groups"][j], k)
+                           for j, k in enumerate(pattern))
+            + sum(fused(params["extra"][r], state["extra"][r], pattern[r])
+                  for r in range(remainder)))
+
+
 def decode_step(
     params: dict,
     state: dict,
@@ -507,50 +596,21 @@ def decode_step(
     PRNG threading, so greedy decode matches the packed chain bit-for-bit
     at gain 1.0."""
     nx = nx or Numerics(QuantConfig(mode="float"))
-    b = token.shape[0]
     positions = state["position"][:, None]                   # (B, 1)
-    pt = state.get("page_table")
     tok = token[:, None] if token.ndim == 1 else token[:, None, :]
     x = _embed(params, tok, mcfg, positions)
-
-    pattern, n_groups, remainder = _pattern(mcfg)
-    glen = len(pattern)
-
-    def body(x, xs):
-        gparams, gstate, g_enc_kv, g = xs
-        new_states = []
-        for j, kind in enumerate(pattern):
-            nxj = nx.fold(g * glen + j)
-            ek = g_enc_kv[j] if g_enc_kv is not None else None
-            x, st, _ = _apply_layer(
-                gparams[j], x, mcfg, kind, nxj,
-                positions=positions, state=gstate[j], enc_kv=ek,
-                page_table=pt)
-            new_states.append(st)
-        return x, tuple(new_states)
-
-    x, new_group_states = jax.lax.scan(
-        body, x,
-        (params["groups"], state["groups"], enc_kv, jnp.arange(n_groups)))
-
-    new_extra = []
-    for r in range(remainder):
-        kind = pattern[r]
-        x, st, _ = _apply_layer(
-            params["extra"][r], x, mcfg, kind, nx.fold(n_groups * glen + r),
-            positions=positions, state=state["extra"][r], enc_kv=None,
-            page_table=pt)
-        new_extra.append(st)
+    x, new_group_states, new_extra = _run_layers(
+        params, state, x, mcfg, nx, positions=positions, enc_kv=enc_kv)
 
     x = norm(x, params["final_norm"], mcfg.norm_type)
     logits = _lm_head(params, x, mcfg, nx.fold(999_983))[:, 0]
     new_state = {
         "groups": new_group_states,
-        "extra": tuple(new_extra),
+        "extra": new_extra,
         "position": state["position"] + 1,
     }
-    if pt is not None:
-        new_state["page_table"] = pt
+    if "page_table" in state:
+        new_state["page_table"] = state["page_table"]
     return logits, new_state
 
 
@@ -590,39 +650,12 @@ def prefill(
     position, and a chunked matmul grid differs from S decode-shaped grids.
     """
     nx = nx or Numerics(QuantConfig(mode="float"))
-    b, s = tokens.shape[:2]
+    s = tokens.shape[1]
     positions = state["position"][:, None] + jnp.arange(s)[None, :]
-    pt = state.get("page_table")
     x = _embed(params, tokens, mcfg, positions)
-
-    pattern, n_groups, remainder = _pattern(mcfg)
-    glen = len(pattern)
-
-    def body(x, xs):
-        gparams, gstate, g_enc_kv, g = xs
-        new_states = []
-        for j, kind in enumerate(pattern):
-            nxj = nx.fold(g * glen + j)
-            ek = g_enc_kv[j] if g_enc_kv is not None else None
-            x, st, _ = _apply_layer(
-                gparams[j], x, mcfg, kind, nxj,
-                positions=positions, state=gstate[j], enc_kv=ek,
-                n_tokens=n_tokens, page_table=pt)
-            new_states.append(st)
-        return x, tuple(new_states)
-
-    x, new_group_states = jax.lax.scan(
-        body, x,
-        (params["groups"], state["groups"], enc_kv, jnp.arange(n_groups)))
-
-    new_extra = []
-    for r in range(remainder):
-        kind = pattern[r]
-        x, st, _ = _apply_layer(
-            params["extra"][r], x, mcfg, kind, nx.fold(n_groups * glen + r),
-            positions=positions, state=state["extra"][r], enc_kv=None,
-            n_tokens=n_tokens, page_table=pt)
-        new_extra.append(st)
+    x, new_group_states, new_extra = _run_layers(
+        params, state, x, mcfg, nx, positions=positions, enc_kv=enc_kv,
+        n_tokens=n_tokens)
 
     x = norm(x, params["final_norm"], mcfg.norm_type)
     last = jnp.clip(n_tokens - 1, 0, s - 1)
@@ -630,11 +663,11 @@ def prefill(
     logits = _lm_head(params, x_last, mcfg, nx.fold(999_983))[:, 0]
     new_state = {
         "groups": new_group_states,
-        "extra": tuple(new_extra),
+        "extra": new_extra,
         "position": state["position"] + n_tokens,
     }
-    if pt is not None:
-        new_state["page_table"] = pt
+    if "page_table" in state:
+        new_state["page_table"] = state["page_table"]
     return logits, new_state
 
 

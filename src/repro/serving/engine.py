@@ -119,7 +119,8 @@ puts each host step on the clock of the device's executables:
 ``serving.admit`` (args ``admitted``, ``queued``), ``serving.step`` around
 all of ``step()``, inside it ``serving.prefill_pass`` (``pass_id``,
 ``bucket``, ``rows`` = capacity x bucket, ``tokens`` fed, ``live``) or
-``serving.decode_tick`` (``pass_id``, ``live``), and inside those
+``serving.decode_tick`` (``pass_id``, ``live``, ``fused_layers``: the
+layers whose tick runs the fused attention kernel), and inside those
 ``serving.launch`` (``pass_id``) around the executable's dispatch.  The
 stream adds ``serving.stream_wait``, ``serving.fetch`` (``pass_id``) and,
 on delivery, ``serving.deliver`` (``pass_id``); garbage collections show as
@@ -1374,7 +1375,8 @@ class ServingEngine:
     def _decode_tick(self):
         pass_id = self.ticks
         fed = [i for i, s in enumerate(self.slots) if s is not None]
-        with span("serving.decode_tick", pass_id=pass_id, live=len(fed)):
+        with span("serving.decode_tick", pass_id=pass_id,
+                  live=len(fed)) as sp:
             if self.paged:
                 # A private copy: JAX may read a host array after the call
                 # returns (on the CPU backend in place), and the host goes
@@ -1393,6 +1395,7 @@ class ServingEngine:
             self.metrics.window_open(t0)
             (logits, sampled, self.state), warm = self._call(
                 ("decode",), args)
+            sp.set_metadata(fused_layers=self.runner.fused_layers)
             self._dev_next = sampled
             self._ov_vals[:] = 0
             self._ov_mask[:] = False

@@ -44,6 +44,7 @@ from repro.models import (
     decode_step,
     encode,
     encode_cross_kv,
+    fused_decode_layers,
     init_decode_state,
     prefill,
     sample_tokens,
@@ -76,6 +77,9 @@ class ModelRunner:
     #: Is cross-request prefix-page sharing sound for this model?  (False
     #: when decoder state depends on per-request side inputs — EncDec.)
     prefix_cache_ok: bool = True
+    #: Layers whose decode tick runs the fused attention kernel, counted
+    #: when the step was last traced (``models.fused_decode_layers``).
+    fused_layers: int = 0
 
     def __init__(self, mcfg: ModelConfig):
         self.mcfg = mcfg
@@ -132,6 +136,12 @@ class ModelRunner:
         nx = Numerics(quant, key, mesh=mesh)
         return prefill(params, state, tokens, n_tokens, self.mcfg, nx)
 
+    def _traced_step(self, params, state, token, key, quant, mesh):
+        """``_step_core``, counting ``fused_layers`` as it is traced."""
+        self.fused_layers = fused_decode_layers(
+            params, state, self.mcfg, Numerics(quant, mesh=mesh))
+        return self._step_core(params, state, token, key, quant, mesh)
+
     def make_step(self, quant, mesh, seed=None):
         """Build the jit-ready decode-tick closure.
 
@@ -156,16 +166,16 @@ class ModelRunner:
         """
         if seed is None:
             def _step(params, state, token, key):
-                return self._step_core(params, state, token, key, quant,
-                                       mesh)
+                return self._traced_step(params, state, token, key, quant,
+                                         mesh)
 
             return _step
 
         def _step(params, state, token, ov_vals, ov_mask, key, temps, uids,
                   idxs):
             tok = jnp.where(ov_mask, ov_vals, token)
-            logits, new_state = self._step_core(params, state, tok, key,
-                                                quant, mesh)
+            logits, new_state = self._traced_step(params, state, tok, key,
+                                                  quant, mesh)
             nxt = self._replicated(
                 sample_tokens(logits, temps, uids, idxs, seed), mesh)
             return logits, nxt, new_state

@@ -18,6 +18,7 @@ Three contracts:
 import dataclasses
 
 import jax
+import jax.extend.core as jex_core
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -112,18 +113,36 @@ def test_fused_qkv_rejects_mismatched_weights():
 # ---------------------------------------------------------------------------
 
 
+def _kv_cache(rng, b, kh, d, s):
+    """A random int8 cache (codes, scales) and one new token per slot."""
+    codes = lambda *sh: jnp.asarray(  # noqa: E731
+        rng.integers(-127, 128, size=sh), jnp.int8)
+    scales = lambda *sh: jnp.asarray(  # noqa: E731
+        rng.uniform(0.1, 2.0, size=sh), jnp.bfloat16)
+    return (codes(b, kh, d, s), codes(b, kh, d, s), scales(b, kh, 2, s),
+            (codes(b, kh, d), codes(b, kh, d), scales(b, kh, 2)))
+
+
+def _appended_np(kc, vc, sc, new, lengths):
+    """The cache with each slot's new token at position lengths - 1."""
+    out = [np.array(a) for a in (kc, vc, sc)]
+    for a, col in zip(out, new):
+        for i, n in enumerate(np.asarray(lengths)):
+            a[i, ..., n - 1] = np.asarray(col)[i]
+    return tuple(jnp.asarray(a) for a in out)
+
+
 @pytest.mark.parametrize("kh,h", [(2, 8), (4, 4)])
 def test_fused_attention_bit_identical(kh, h):
     rng = np.random.default_rng(kh * 17 + h)
     B, S, D = 3, 16, 64
     q = jnp.asarray(rng.normal(size=(B, 1, h, D)), jnp.bfloat16)
-    kc = jnp.asarray(rng.integers(-127, 128, size=(B, S, kh, D)), jnp.int8)
-    vc = jnp.asarray(rng.integers(-127, 128, size=(B, S, kh, D)), jnp.int8)
-    ks = jnp.asarray(rng.uniform(0.1, 2.0, size=(B, S, kh)), jnp.bfloat16)
-    vs = jnp.asarray(rng.uniform(0.1, 2.0, size=(B, S, kh)), jnp.bfloat16)
+    kc, vc, sc, new = _kv_cache(rng, B, kh, D, S)
     ln = jnp.asarray([1, 7, 16], jnp.int32)
-    ref = quantized_decode_attention(q, kc, ks, vc, vs, lengths=ln)
-    got = fused_quantized_decode_attention(q, kc, ks, vc, vs, lengths=ln)
+    ref = quantized_decode_attention(
+        q, *_appended_np(kc, vc, sc, new, ln), lengths=ln)
+    got, *_ = fused_quantized_decode_attention(q, kc, vc, sc, *new,
+                                               lengths=ln)
     np.testing.assert_array_equal(np.asarray(ref, np.float32),
                                   np.asarray(got, np.float32))
 
@@ -233,6 +252,131 @@ def test_fused_decode_step_bit_identical_to_packed_chain(tinyllama_kvq):
         outs[name] = seq
     for a, b in zip(outs["packed"], outs["fused"]):
         np.testing.assert_array_equal(a, b)
+
+
+def _filled_state(mcfg, lengths, s_max, seed=0):
+    """A decode state whose int8 cache holds random codes and scales at
+    every position, with the slots' lengths (and positions) set."""
+    rng = np.random.default_rng(seed)
+    st = init_decode_state(mcfg, len(lengths), s_max)
+    kv = st["groups"][0]["kv"]
+    ln = jnp.asarray(lengths, jnp.int32)
+    kv = {"k": jnp.asarray(rng.integers(-127, 128, kv["k"].shape), jnp.int8),
+          "v": jnp.asarray(rng.integers(-127, 128, kv["v"].shape), jnp.int8),
+          "kv_scale": jnp.asarray(rng.uniform(0.1, 2.0, kv["kv_scale"].shape),
+                                  jnp.bfloat16),
+          "length": jnp.broadcast_to(ln, kv["length"].shape)}
+    return {**st, "groups": ({"kv": kv},), "position": ln}
+
+
+def test_fused_tick_appends_in_place(tinyllama_kvq):
+    """One fused tick over slots of lengths 0, 5 and S - 1 writes each
+    slot's new codes and scales at its own length only — bit-identical to
+    the packed chain's — and leaves every other position byte-equal.  A
+    free slot's length grows with every tick the engine runs; past the
+    cache's end its tick writes nothing."""
+    mcfg, params = tinyllama_kvq
+    s_max, lengths = 16, [0, 5, 15, 19]
+    before = _filled_state(mcfg, lengths, s_max)
+    tok = jnp.asarray([3, 5, 7, 9], jnp.int32)
+    after = {}
+    for name, quant in (("packed", PACKED1), ("fused", FUSED1)):
+        pk = pack_model_params(params, quant, mcfg)
+        _, st = decode_step(pk, jax.tree.map(jnp.copy, before), tok, mcfg,
+                            Numerics(quant, jax.random.PRNGKey(4)))
+        after[name] = st["groups"][0]["kv"]
+    old = before["groups"][0]["kv"]
+    new = jnp.arange(s_max)[None, :] == jnp.asarray(lengths)[:, None]
+    for leaf in ("k", "v", "kv_scale"):
+        a, f, c = (np.asarray(t[leaf], np.float32)
+                   for t in (old, after["fused"], after["packed"]))
+        np.testing.assert_array_equal(f, c)
+        at = np.broadcast_to(np.asarray(new)[None, :, None, None, :], a.shape)
+        np.testing.assert_array_equal(f[~at], a[~at])
+        assert (f[at] != a[at]).any()            # the tick did write
+    np.testing.assert_array_equal(np.asarray(after["fused"]["length"]),
+                                  np.asarray(old["length"]) + 1)
+
+
+def _cache_moves(jaxpr, s_max, kh, found):
+    """Equations outside the Pallas kernels whose output has both the
+    cache-length and the KV-head axis (the scan's and jit's nesting is
+    followed into, a kernel's body is not)."""
+    for eqn in jaxpr.eqns:
+        subs = [v for v in eqn.params.values()
+                if isinstance(v, (jex_core.Jaxpr, jex_core.ClosedJaxpr))]
+        if eqn.primitive.name == "pallas_call":
+            continue
+        if subs:
+            for sub in subs:
+                _cache_moves(getattr(sub, "jaxpr", sub), s_max, kh, found)
+            continue
+        if any({s_max, kh} <= set(getattr(v.aval, "shape", ()))
+               for v in eqn.outvars):
+            found.append(eqn.primitive.name)
+    return found
+
+
+def test_fused_decode_step_moves_no_cache():
+    """The test-size counterpart of tests/test_tpu_compile.py's guard, on
+    the program handed to the compiler: in the fused decode step nothing
+    but the Pallas attention kernel, which appends in place, makes a value
+    with both the cache-length and the KV-head axis.  (XLA:CPU runs the
+    kernel in interpret mode, which copies an aliased operand per call, so
+    its compiled HLO cannot show what the TPU's does.)"""
+    mcfg = dataclasses.replace(smoke_config("tinyllama-1.1b"), kv_quant=True,
+                               num_heads=8, num_kv_heads=4)
+    s_max = 24
+    pk = pack_model_params(init_params(jax.random.PRNGKey(0), mcfg), FUSED1,
+                           mcfg)
+    st = init_decode_state(mcfg, 3, s_max)
+    tok = jnp.zeros((3,), jnp.int32)
+
+    def step(p, s, t):
+        return decode_step(p, s, t, mcfg,
+                           Numerics(FUSED1, jax.random.PRNGKey(0)))
+
+    jaxpr = jax.make_jaxpr(step)(pk, st, tok)
+    assert _cache_moves(jaxpr.jaxpr, s_max, 4, []) == []
+    # The guard sees what it guards: the packed chain's jnp attention and
+    # its per-layer reads of the cache are caught.
+    chain = jax.make_jaxpr(lambda p, s, t: decode_step(
+        p, s, t, mcfg, Numerics(PACKED1, jax.random.PRNGKey(0))))(
+        pack_model_params(init_params(jax.random.PRNGKey(0), mcfg), PACKED1,
+                          mcfg), st, tok)
+    assert _cache_moves(chain.jaxpr, s_max, 4, [])
+
+
+@pytest.mark.parametrize("start,count", [
+    ([0, 100, 250, 256], [128, 128, 128, 0]),   # first tile, across, past end
+    ([5, 127, 128, 0], [1, 1, 1, 0]),           # decode-sized appends
+])
+def test_append_kv_columns_writes_only_new_positions(start, count):
+    """The Pallas append writes slot b's columns at start[b] + [0,
+    count[b]) and nothing else — across a 128-column tile edge, and
+    dropping what falls past the cache's end — in a stacked cache at one
+    layer."""
+    from repro.kernels.abfp_decode_fused import append_kv_columns
+
+    rng = np.random.default_rng(7)
+    L, B, KH, D, S, C = 2, 4, 2, 8, 256, max(count)
+    codes = lambda *sh: jnp.asarray(  # noqa: E731
+        rng.integers(-127, 128, size=sh), jnp.int8)
+    old = (codes(L, B, KH, D, S), codes(L, B, KH, D, S),
+           jnp.asarray(rng.uniform(0.1, 2, (L, B, KH, 2, S)), jnp.bfloat16))
+    cols = (codes(B, KH, D, C), codes(B, KH, D, C),
+            jnp.asarray(rng.uniform(0.1, 2, (B, KH, 2, C)), jnp.bfloat16))
+    got = append_kv_columns(
+        *[jnp.copy(a) for a in old], *cols, start=jnp.asarray(start),
+        count=jnp.asarray(count), layer=jnp.int32(1))
+    for a, c, g in zip(old, cols, got):
+        want = np.array(a)
+        for b in range(B):
+            for j in range(count[b]):
+                if start[b] + j < S:
+                    want[1, b, ..., start[b] + j] = np.asarray(c)[b, ..., j]
+        np.testing.assert_array_equal(np.asarray(g, np.float32),
+                                      want.astype(np.float32))
 
 
 def test_fused_engine_serving_path(tinyllama_kvq):

@@ -34,11 +34,12 @@ def _mcfg(name):
     if name == "tinyllama-kvquant":
         return dataclasses.replace(smoke_config("tinyllama-1.1b"),
                                    kv_quant=True)
-    if name == "hybrid-window8":
+    if name.startswith("hybrid-window8"):
         # Window smaller than the prompt: exercises ring-buffer wraparound
         # inside and across chunks.
         return dataclasses.replace(smoke_config("recurrentgemma-2b"),
-                                   window_size=8)
+                                   window_size=8,
+                                   kv_quant=name.endswith("kvquant"))
     return smoke_config(name)
 
 
@@ -75,7 +76,7 @@ def _assert_trees_bitwise(t1, t2):
 
 
 ARCHS = ["tinyllama-1.1b", "recurrentgemma-2b", "xlstm-350m",
-         "tinyllama-kvquant", "hybrid-window8"]
+         "tinyllama-kvquant", "hybrid-window8", "hybrid-window8-kvquant"]
 
 
 @pytest.mark.parametrize("arch", ARCHS)

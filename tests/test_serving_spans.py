@@ -9,6 +9,7 @@ of the engine's ``_call`` records what each dispatched pass really fed, so
 the spans' args are checked against the dispatch itself.
 """
 
+import dataclasses
 import gc
 import glob
 import time
@@ -49,19 +50,20 @@ class Ev:
         return other.start <= self.start and self.end <= other.end
 
 
-def _engine(tiny, overlap):
+def _engine(tiny, overlap, quant=QuantConfig(mode="float")):
     params, mcfg = tiny
+    if quant.mode != "float":
+        mcfg = dataclasses.replace(mcfg, kv_quant=True)
     return ServingEngine(params, mcfg, capacity=3, max_len=48,
-                         quant=QuantConfig(mode="float"),
-                         prefill_chunks=(4, 8), clock=time.perf_counter,
-                         overlap=overlap)
+                         quant=quant, prefill_chunks=(4, 8),
+                         clock=time.perf_counter, overlap=overlap)
 
 
-def _serve_traced(tiny, overlap, trace_dir):
+def _serve_traced(tiny, overlap, trace_dir, **engine_kw):
     """Serve ``PROMPTS`` (four requests, three slots) under the profiler;
     returns the engine, the passes its ``_call`` dispatched as
     (kind, real tokens fed), and the trace's serving and GC events."""
-    eng = _engine(tiny, overlap)
+    eng = _engine(tiny, overlap, **engine_kw)
     eng.warmup()
     dispatched = []
     call = eng._call
@@ -181,6 +183,20 @@ def test_engine_spans_in_profiler_trace(tiny, tmp_path, overlap):
     tokens = sum(p.args["tokens"] for p in prefill)
     assert (pre["rows"], pre["tokens"]) == (rows, tokens)
     assert pre["pad_share"] == pytest.approx(1 - tokens / rows)
+
+
+@pytest.mark.parametrize("mode", ["abfp_fused", "abfp_packed", "float"])
+def test_decode_tick_counts_fused_layers(tiny, tmp_path, mode):
+    """``fused_layers`` on every decode tick: the layers whose tick runs
+    the fused attention kernel on the int8 cache — all of them in the
+    fused mode, none in the packed chain or in float."""
+    _, mcfg = tiny
+    quant = QuantConfig(mode=mode, tile_width=32, gain=1.0, noise_lsb=0.5)
+    _, _, events = _serve_traced(tiny, True, tmp_path, quant=quant)
+    ticks = [e for e in events if e.name == "serving.decode_tick"]
+    assert ticks
+    want = mcfg.num_layers if mode == "abfp_fused" else 0
+    assert {e.args["fused_layers"] for e in ticks} == {want}
 
 
 def test_prefill_counters_without_prefill():
